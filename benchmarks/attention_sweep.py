@@ -86,21 +86,21 @@ def sweep(backends: Sequence[str], dtype: str = "float32",
 
 def run():
     """Default suite entry (benchmarks.run): CPU-safe backends."""
-    sweep(("unfused", "fused_interpret"), dtype="float32", decode_cache=256)
+    sweep(("unfused", "fused"), dtype="float32", decode_cache=256)
 
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--backend", default=None,
                     help="pin one attention backend (default: unfused + "
-                         "fused_interpret)")
+                         "fused)")
     ap.add_argument("--dtype", default="float32",
                     choices=["float32", "bfloat16"])
     ap.add_argument("--decode-cache", type=int, default=512,
                     help="KV cache length of the serving decode cell")
     args = ap.parse_args(argv)
     backends = ((args.backend,) if args.backend
-                else ("unfused", "fused_interpret"))
+                else ("unfused", "fused"))
     sweep(backends, dtype=args.dtype, decode_cache=args.decode_cache)
     return 0
 

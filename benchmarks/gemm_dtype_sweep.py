@@ -24,7 +24,7 @@ from repro.core import sysmodel as SM
 from repro.core.plan import GemmPolicy
 from repro.kernels.matrixflow_gemm import matrixflow_gemm
 
-POLICY_BACKENDS = ("xla", "blockflow", "pallas_interpret")
+POLICY_BACKENDS = ("xla", "blockflow", "pallas")
 
 
 def _load_parity():

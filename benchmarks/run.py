@@ -13,6 +13,7 @@ from benchmarks import (attention_sweep, gemm_dtype_sweep, gemm_size_sweep,
                         interconnect_sweep, roofline_table, runtime_breakdown,
                         serving_sweep, transformer_e2e)
 from benchmarks.common import dump_csv
+from repro.launch.compile_cache import use_compile_cache
 
 SUITES = {
     "fig7": gemm_size_sweep.run,
@@ -40,6 +41,7 @@ SUITES = {
 
 def main(argv=None) -> int:
     argv = argv if argv is not None else sys.argv[1:]
+    use_compile_cache()
     picks = [a for a in argv if not a.startswith("-")] or list(SUITES)
     for name in picks:
         print(f"\n===== {name} =====")

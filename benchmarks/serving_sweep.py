@@ -239,7 +239,7 @@ def sweep(arch: str = "smollm-135m", n_layers: int = 2, max_len: int = 64,
     n_blocks = -(-max_len // page_size)
     cache_pages = max(n_blocks,
                       int(batch_slots * n_blocks * cache_pages_frac))
-    paged_attn = AttentionPolicy(backend="paged_interpret",
+    paged_attn = AttentionPolicy(backend="paged",
                                  page_size=page_size, block_q=16)
     cells = {
         "contiguous": ServeConfig(
@@ -323,7 +323,7 @@ def sweep_prefix(arch: str = "smollm-135m", n_layers: int = 2,
     # pool sized so an UNSHARED request set is page-starved (~2 concurrent)
     # while shared prefixes fit many: the capacity the cache must unlock
     pages = cache_pages if cache_pages is not None else 2 * n_blocks
-    paged_attn = AttentionPolicy(backend="paged_interpret",
+    paged_attn = AttentionPolicy(backend="paged",
                                  page_size=page_size, block_q=16)
     base = dict(batch_slots=batch_slots, max_len=max_len,
                 attention=paged_attn, cache_pages=pages)
@@ -407,7 +407,7 @@ def sweep_kv(arch: str = "smollm-135m", n_layers: int = 2,
                  + 2 * cfg.n_kv_heads * 4 * cfg.n_layers)   # fp32 scales
     budget = fp_pages * fp_page
     int8_pages = budget // int8_page
-    paged_attn = AttentionPolicy(backend="paged_interpret",
+    paged_attn = AttentionPolicy(backend="paged",
                                  page_size=page_size, block_q=16)
     base = dict(batch_slots=batch_slots, max_len=max_len,
                 attention=paged_attn, cache_dtype="bfloat16")
@@ -535,7 +535,7 @@ def sweep_spec(arch: str = "smollm-135m", n_layers: int = 2,
     if max_len is None:
         # headroom for the longest prompt + gen_len with pages to spare
         max_len = gen_len + 16
-    paged_attn = AttentionPolicy(backend="paged_interpret",
+    paged_attn = AttentionPolicy(backend="paged",
                                  page_size=page_size, block_q=16)
     base = dict(batch_slots=batch_slots, max_len=max_len,
                 attention=paged_attn)

@@ -50,7 +50,7 @@ def main():
     # -- 4. the ExecutionPlan API ------------------------------------------
     # A GemmPolicy is a frozen description of HOW GEMMs execute; plan()
     # resolves it per shape (memoized), consulting the sysmodel for DC/DM.
-    policy = api.GemmPolicy(backend="pallas_interpret", mode="auto")
+    policy = api.GemmPolicy(backend="pallas", mode="auto")
     pln = api.plan(256, 384, 512, jnp.float32, policy)
     print(f"plan(256,384,512): backend={pln.backend} mode={pln.mode} "
           f"layout={pln.layout}  (cache: {api.plan_cache_info()})")

@@ -49,7 +49,7 @@ def main():
     from repro.core.plan import AttentionPolicy
     engine3 = ServingEngine(cfg, params, ServeConfig(
         batch_slots=4, max_len=32,
-        attention=AttentionPolicy(backend="paged_interpret", page_size=8,
+        attention=AttentionPolicy(backend="paged", page_size=8,
                                   block_q=8),
         cache_pages=8))
     rids = [engine3.submit(rng.integers(0, cfg.vocab, 3).tolist())

@@ -138,10 +138,11 @@ def sweep_attention(cases: Sequence[Tuple] = ATTN_CASES,
         bt = _paged_block_tables(B, T, page_size)
         P_pages = B * bt.shape[1] + 3
         for quantized in (False, True):
+            # the parity cells run interpreted (head dim 16)
             c = paged(B=B, Sq=Sq, H=H, Hkv=Hkv, D=ATTN_HEAD_DIM,
                       Dv=ATTN_HEAD_DIM, P=P_pages, page_size=page_size,
                       block_tables=bt, block_q=ATTN_BLOCK,
-                      quantized=quantized)
+                      quantized=quantized, interpret=True)
             suffix = " int8-kv" if quantized else ""
             entries.append(SweepEntry(
                 "paged_attention", f"paged {name}{suffix}",

@@ -26,7 +26,7 @@ Rules (each is a ``LintFinding.rule``):
                        the kernel would consume raw quantized codes as if
                        they were values (docs/quant.md#kv-pages).
 
-``lint_jaxpr`` walks any ClosedJaxpr recursively (pjit bodies, scan/while
+``lint_jaxpr`` walks any ClosedJaxpr recursively (jit bodies, scan/while
 carries, cond branches); ``lint_engine`` traces a live ``ServingEngine``'s
 prefill and decode closures with ``jax.make_jaxpr`` — abstract evaluation
 only, nothing is executed and no device memory moves.
@@ -62,7 +62,7 @@ class LintFinding:
 
     rule: str                  # one of LINT_RULES
     message: str
-    path: str                  # e.g. "decode/pjit:decode_step/scan"
+    path: str                  # e.g. "decode/jit:decode_step/scan"
 
     def __str__(self) -> str:
         return f"[{self.rule}] {self.path}: {self.message}"
@@ -79,7 +79,7 @@ def _is_f64(var) -> bool:
 
 def _sub_jaxprs(params: dict):
     """Yield (name, jaxpr) for every sub-jaxpr in an eqn's params —
-    pjit/scan/while bodies, cond branches — by duck-typing, so the walk
+    jit/scan/while bodies, cond branches — by duck-typing, so the walk
     survives jax version renames."""
     for key, val in params.items():
         vals = val if isinstance(val, (list, tuple)) else (val,)
@@ -133,28 +133,30 @@ def _walk(jaxpr, path: str, findings: List[LintFinding],
 
 
 def _check_pallas_scales(eqn, path: str, findings: List[LintFinding]) -> None:
-    """int8 KV pools (rank >= 4 int8 operands: (P, page_size, Hkv, D)) must
-    be accompanied by fp32 rank-2 (P, Hkv) scale operands in the same call
-    — the paged kernel's in-register dequant contract."""
-    pools = []
-    scales = 0
+    """int8 KV pools (int8 operands of rank >= 3: the paged kernel sees
+    (P, page_size, Hkv*D)) must be accompanied by fp32 (P, 1, Hkv) scale
+    rows in the same call — the paged kernel's in-register dequant
+    contract."""
+    pools, scales = [], []
     for var in eqn.invars:
         a = _aval(var)
         if a is None:
             continue
         dtype = getattr(a, "dtype", None)
-        shape = getattr(a, "shape", ())
-        if dtype == jnp.int8 and len(shape) >= 4:
+        shape = tuple(getattr(a, "shape", ()))
+        if dtype == jnp.int8 and len(shape) >= 3:
             pools.append(shape)
-        elif dtype == jnp.float32 and len(shape) == 2:
-            scales += 1
-    if pools and scales < len(pools):
+        elif dtype == jnp.float32 and len(shape) == 3 and shape[1] == 1:
+            scales.append(shape)
+    n_scales = sum(1 for s in scales if any(s[0] == p[0] for p in pools))
+    if pools and n_scales < len(pools):
         findings.append(LintFinding(
             "int8-pool-no-scales",
             f"pallas_call consumes {len(pools)} int8 page pool(s) "
-            f"{pools} but only {scales} rank-2 fp32 scale operand(s): "
-            f"the kernel would treat quantized codes as values — pass "
-            f"kv_scales=(k_scales, v_scales) of shape (P, Hkv)",
+            f"{pools} but only {n_scales} fp32 (P, 1, Hkv) scale "
+            f"operand(s): the kernel would treat quantized codes as "
+            f"values — pass kv_scales=(k_scales, v_scales) of shape "
+            f"(P, Hkv)",
             path))
 
 

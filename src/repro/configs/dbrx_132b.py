@@ -17,7 +17,5 @@ CONFIG = ModelConfig(
     moe_d_ff=10752,
     mlp_act="swiglu",
     rope_theta=5e5,
-    # kv=8 heads not divisible by TP=16 → replicate KV projections
-    sharding_overrides=(("kv_heads", None),),
     source="hf:databricks/dbrx-base; unverified",
 )

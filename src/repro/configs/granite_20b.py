@@ -16,6 +16,5 @@ CONFIG = ModelConfig(
     vocab=49152,
     rope_theta=1e4,
     mlp_act="gelu",
-    sharding_overrides=(("kv_heads", None),),  # MQA: single KV head replicated
     source="arXiv:2405.04324; hf",
 )

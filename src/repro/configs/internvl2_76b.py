@@ -16,7 +16,6 @@ CONFIG = ModelConfig(
     d_ff=28672,
     vocab=128256,
     rope_theta=5e5,
-    sharding_overrides=(("kv_heads", None),),
     source="arXiv:2404.16821; unverified",
 )
 

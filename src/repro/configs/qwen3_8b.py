@@ -15,6 +15,5 @@ CONFIG = ModelConfig(
     vocab=151936,
     qk_norm=True,
     rope_theta=1e6,
-    sharding_overrides=(("kv_heads", None),),  # kv=8 < TP=16
     source="hf:Qwen/Qwen3-8B; hf",
 )

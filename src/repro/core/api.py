@@ -13,8 +13,8 @@ Built-in backends (registered here; add your own via
                       (XLA already tiles for the MXU; the MatrixFlow schedule
                       is a kernel-level concern). Consumes batched
                       contractions natively.
-  "pallas"            the MatrixFlow Pallas kernel (TPU target).
-  "pallas_interpret"  same kernel, interpret mode (CPU validation).
+  "pallas"            the MatrixFlow Pallas kernel (TPU target; interpret
+                      mode where there is no TPU).
   "blockflow"         the faithful Algorithm-1 lax rendering (paper baseline).
 
 The default policy is ``GemmPolicy()`` — backend "auto" (pallas on TPU, xla
@@ -36,11 +36,11 @@ dequant fused into the C-block flush on the block-major backends.
 Attention has the same shape: :func:`attention` routes every model
 attention call through an :class:`~repro.core.plan.AttentionPolicy` and its
 own backend registry — ``fused`` (the offset-aware flash Pallas kernel,
-kernels/flash_attention.py), ``fused_interpret`` (CPU validation),
-``unfused`` (the paper's §4.4 einsum + host-softmax split), and ``paged`` /
-``paged_interpret`` (the block-table paged-KV kernel,
+kernels/flash_attention.py), ``unfused`` (the paper's §4.4 einsum +
+host-softmax split), and ``paged`` (the block-table paged-KV kernel,
 kernels/paged_attention.py — K/V live in a page pool and a per-request
-block table drives the fetch; docs/serving.md). Pin with
+block table drives the fetch; docs/serving.md). The Pallas kernels run
+compiled on a TPU and in interpret mode everywhere else. Pin with
 :func:`use_attention_policy`; see docs/attention.md.
 
 Migration from the old stringly-typed API (kept as deprecation shims for one
@@ -176,34 +176,32 @@ def _blockflow_gemm(a2, b, pln: ExecutionPlan, out_dtype):
                                   acc_dtype=pln.acc)
 
 
-def _make_pallas_gemm(interpret: bool):
-    def pallas_gemm(a2, b, pln: ExecutionPlan, out_dtype):
-        from repro.kernels import matrixflow_gemm as mf  # lazy: pallas import
-        if isinstance(b, QuantizedPackedWeight):
-            aq, sa = Q.quantize_activations(a2)
-            blk = P.layout_for_packed(a2.shape[0], b, jnp.int8, pln.policy)
-            a_bm = L.to_block_major_a(aq, blk.bm, blk.bk)
-            c_bm = mf.matrixflow_gemm_block_major(
-                a_bm, b.data, blk=blk, out_dtype=out_dtype,
-                interpret=interpret, acc_dtype=jnp.int32,
-                scale_a=sa, scale_b=b.scales)
-            return L.from_block_major_c(c_bm, a2.shape[0], b.n)
-        if isinstance(b, PackedWeight):
-            blk = P.layout_for_packed(a2.shape[0], b, a2.dtype, pln.policy)
-            a_bm = L.to_block_major_a(a2, blk.bm, blk.bk)
-            c_bm = mf.matrixflow_gemm_block_major(
-                a_bm, b.data, blk=blk, out_dtype=out_dtype,
-                interpret=interpret, acc_dtype=pln.acc)
-            return L.from_block_major_c(c_bm, a2.shape[0], b.n)
-        return mf.matrixflow_gemm(a2, b, blk=pln.layout, out_dtype=out_dtype,
-                                  interpret=interpret, acc_dtype=pln.acc)
-    return pallas_gemm
+def _pallas_gemm(a2, b, pln: ExecutionPlan, out_dtype):
+    from repro.kernels import matrixflow_gemm as mf  # lazy: pallas import
+    interpret = not P.on_tpu()       # compiled on a TPU only
+    if isinstance(b, QuantizedPackedWeight):
+        aq, sa = Q.quantize_activations(a2)
+        blk = P.layout_for_packed(a2.shape[0], b, jnp.int8, pln.policy)
+        a_bm = L.to_block_major_a(aq, blk.bm, blk.bk)
+        c_bm = mf.matrixflow_gemm_block_major(
+            a_bm, b.data, blk=blk, out_dtype=out_dtype,
+            interpret=interpret, acc_dtype=jnp.int32,
+            scale_a=sa, scale_b=b.scales)
+        return L.from_block_major_c(c_bm, a2.shape[0], b.n)
+    if isinstance(b, PackedWeight):
+        blk = P.layout_for_packed(a2.shape[0], b, a2.dtype, pln.policy)
+        a_bm = L.to_block_major_a(a2, blk.bm, blk.bk)
+        c_bm = mf.matrixflow_gemm_block_major(
+            a_bm, b.data, blk=blk, out_dtype=out_dtype,
+            interpret=interpret, acc_dtype=pln.acc)
+        return L.from_block_major_c(c_bm, a2.shape[0], b.n)
+    return mf.matrixflow_gemm(a2, b, blk=pln.layout, out_dtype=out_dtype,
+                              interpret=interpret, acc_dtype=pln.acc)
 
 
 register_backend("xla", _xla_gemm, batched=True, needs_layout=False)
 register_backend("blockflow", _blockflow_gemm)
-register_backend("pallas", _make_pallas_gemm(interpret=False))
-register_backend("pallas_interpret", _make_pallas_gemm(interpret=True))
+register_backend("pallas", _pallas_gemm)
 
 
 # ---------------------------------------------------------------------------
@@ -362,58 +360,47 @@ def _unfused_attention(q, k, v, *, q_positions, kv_valid_len, causal, scale,
     return out.reshape(B, Sq, H, v.shape[-1])
 
 
-def _make_fused_attention(interpret: bool):
-    def fused_attention(q, k, v, *, q_positions, kv_valid_len, causal, scale,
-                        soft_cap, policy, block_tables=None, kv_scales=None):
-        _reject_paged("fused_interpret" if interpret else "fused",
-                      block_tables, kv_scales)
-        from repro.kernels import ops  # lazy: pallas import
-        return ops.mha(q, k, v, causal=causal, scale=scale,
-                       soft_cap=soft_cap, q_positions=q_positions,
-                       kv_valid_len=kv_valid_len,
-                       impl="interpret" if interpret else "pallas",
-                       block_q=policy.block_q, block_k=policy.block_k)
-    return fused_attention
+def _fused_mha(q, k, v, *, q_positions, kv_valid_len, causal, scale,
+               soft_cap, policy):
+    from repro.kernels import ops  # lazy: pallas import; picks the platform
+    return ops.mha(q, k, v, causal=causal, scale=scale, soft_cap=soft_cap,
+                   q_positions=q_positions, kv_valid_len=kv_valid_len,
+                   block_q=policy.block_q, block_k=policy.block_k)
 
 
-def _make_paged_attention(interpret: bool):
-    def paged(q, k, v, *, q_positions, kv_valid_len, causal, scale,
-              soft_cap, policy, block_tables=None, kv_scales=None):
-        """Block-table paged flash attention (kernels/paged_attention.py).
+def _fused_attention(q, k, v, *, block_tables=None, kv_scales=None, **kw):
+    _reject_paged("fused", block_tables, kv_scales)
+    return _fused_mha(q, k, v, **kw)
 
-        With a block table, k/v are the page pools (P, page_size, Hkv, D)
-        and the table drives the kernel's BlockSpec index maps — int8 pools
-        additionally carry ``kv_scales`` (per-page-per-head fp32), which the
-        kernel dequantizes in its K/V-block fetch. Without a block table —
-        cache-less training/scoring, or an MLA latent cache that stays
-        contiguous — the operands are dense and the paged policy degrades
-        to the fused flash kernel (identical contract), so a single policy
-        covers a model end to end.
-        """
-        if block_tables is None:
-            _reject_paged("paged_interpret" if interpret else "paged",
-                          None, kv_scales)
-            from repro.kernels import ops  # lazy: pallas import
-            return ops.mha(q, k, v, causal=causal, scale=scale,
-                           soft_cap=soft_cap, q_positions=q_positions,
-                           kv_valid_len=kv_valid_len,
-                           impl="interpret" if interpret else "pallas",
-                           block_q=policy.block_q, block_k=policy.block_k)
-        from repro.kernels import paged_attention as PA  # lazy: pallas
-        return PA.paged_attention(
-            q, k, v, block_tables, q_positions, kv_valid_len,
-            kv_scales=kv_scales, causal=causal, scale=scale,
-            soft_cap=soft_cap, block_q=policy.block_q, interpret=interpret)
-    return paged
+
+def _paged_attention(q, k, v, *, q_positions, kv_valid_len, causal, scale,
+                     soft_cap, policy, block_tables=None, kv_scales=None):
+    """Block-table paged flash attention (kernels/paged_attention.py).
+
+    With a block table, k/v are the page pools (P, page_size, Hkv, D) and
+    the table drives the kernel's BlockSpec index maps — int8 pools
+    additionally carry ``kv_scales`` (per-page-per-head fp32), which the
+    kernel dequantizes in its K/V-block fetch. Without a block table —
+    cache-less training/scoring, or an MLA latent cache that stays
+    contiguous — the operands are dense and the paged policy degrades to
+    the fused flash kernel (identical contract), so a single policy covers
+    a model end to end.
+    """
+    if block_tables is None:
+        _reject_paged("paged", None, kv_scales)
+        return _fused_mha(q, k, v, q_positions=q_positions,
+                          kv_valid_len=kv_valid_len, causal=causal,
+                          scale=scale, soft_cap=soft_cap, policy=policy)
+    from repro.kernels import paged_attention as PA  # lazy: pallas
+    return PA.paged_attention(
+        q, k, v, block_tables, q_positions, kv_valid_len,
+        kv_scales=kv_scales, causal=causal, scale=scale, soft_cap=soft_cap,
+        block_q=policy.block_q, interpret=not P.on_tpu())
 
 
 register_attention_backend("unfused", _unfused_attention)
-register_attention_backend("fused", _make_fused_attention(interpret=False))
-register_attention_backend("fused_interpret",
-                           _make_fused_attention(interpret=True))
-register_attention_backend("paged", _make_paged_attention(interpret=False))
-register_attention_backend("paged_interpret",
-                           _make_paged_attention(interpret=True))
+register_attention_backend("fused", _fused_attention)
+register_attention_backend("paged", _paged_attention)
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -23,7 +23,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -100,5 +100,5 @@ def pipeline_apply(
         P(),                        # microbatches replicated in
     )
     fn = shard_map(per_stage, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return fn(stage_params, x)

@@ -52,12 +52,19 @@ DEFAULT_RULES: Dict[str, MeshAxes] = {
 
 
 class ShardingRules:
+    """``units`` counts the indivisible groups along a logical dim (heads
+    in a fused ``heads``/``kv_heads`` projection): such a dim shards only
+    when its unit count divides too, never splitting a head across shards
+    (``ModelConfig.head_units()``)."""
+
     def __init__(self, mesh: Optional[Mesh],
-                 overrides: Optional[Dict[str, MeshAxes]] = None):
+                 overrides: Optional[Dict[str, MeshAxes]] = None,
+                 units: Optional[Dict[str, int]] = None):
         self.mesh = mesh
         self.rules = dict(DEFAULT_RULES)
         if overrides:
             self.rules.update(overrides)
+        self.units = dict(units or {})
 
     def _mesh_size(self, axes: MeshAxes) -> int:
         if axes is None or self.mesh is None:
@@ -96,8 +103,10 @@ class ShardingRules:
         parts = []
         for i, name in enumerate(logical):
             axes = self._resolve(self.rules.get(name)) if name else None
-            if axes is not None and shape is not None:
-                if shape[i] % max(self._mesh_size(axes), 1) != 0:
+            if axes is not None:
+                n = max(self._mesh_size(axes), 1)
+                if self.units.get(name, n) % n or (
+                        shape is not None and shape[i] % n):
                     axes = None  # fallback: replicate this dim
             parts.append(axes)
         # duplicate-axis resolution: act_seq yields first, then earlier dims

@@ -45,22 +45,24 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import threading
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import api
 from repro.core.plan import (PackedWeight, QuantizedPackedWeight,
                              ShardingPolicy)
 from repro.distributed.sharding import ShardingRules
+from repro.models.config import ModelConfig
 from repro.models.module import is_axis_leaf
 
 __all__ = [
     "TPContext", "make_context", "use_tp", "current_tp", "head_sharding",
-    "linear", "matmul", "attention", "shard_params", "shard_caches",
+    "linear", "matmul", "attention", "param_shardings", "shard_params",
+    "shard_caches",
     "replicate",
 ]
 
@@ -98,20 +100,23 @@ class TPContext:
 
 def make_context(mesh: Optional[Mesh],
                  policy: Optional[ShardingPolicy] = None,
-                 overrides: Optional[Dict[str, Any]] = None
+                 cfg: Optional[ModelConfig] = None
                  ) -> Optional[TPContext]:
     """Build a TPContext (None mesh → None, the single-device no-op).
 
-    ``overrides`` layer on top of the policy's own (model configs pass
-    ``cfg.overrides_dict()`` — e.g. smollm pins heads replicated).
+    A model ``cfg`` layers its ``sharding_overrides`` on top of the
+    policy's own (e.g. smollm pins heads replicated) and gives its head
+    counts, so no head is split across shards.
     """
     if mesh is None:
         return None
     policy = policy if policy is not None else ShardingPolicy()
     merged = policy.overrides_dict()
-    if overrides:
-        merged.update(overrides)
-    return TPContext(mesh=mesh, rules=ShardingRules(mesh, merged),
+    units = None
+    if cfg is not None:
+        merged.update(cfg.overrides_dict())
+        units = cfg.head_units()
+    return TPContext(mesh=mesh, rules=ShardingRules(mesh, merged, units),
                      policy=policy)
 
 
@@ -223,7 +228,7 @@ def linear(x: jax.Array, w, bias=None, *,
             operands.append(bias)
         fn = shard_map(body, mesh=ctx.mesh, in_specs=tuple(in_specs),
                        out_specs=P(*([None] * (x.ndim - 1)), m),
-                       check_rep=False)
+                       check_vma=False)
         return fn(*operands)
 
     if _sharded_dim(ctx, k_name, K, units):
@@ -240,7 +245,7 @@ def linear(x: jax.Array, w, bias=None, *,
         fn = shard_map(
             body, mesh=ctx.mesh,
             in_specs=(P(*([None] * (x.ndim - 1)), m), P(m, None)),
-            out_specs=P(*([None] * x.ndim)), check_rep=False)
+            out_specs=P(*([None] * x.ndim)), check_vma=False)
         y = fn(x, w)
         if acc is not None:
             y = y.astype(out_dtype)
@@ -318,7 +323,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                              block_tables=bt_, kv_scales=sc_, policy=pol)
 
     fn = shard_map(body, mesh=ctx.mesh, in_specs=tuple(in_specs),
-                   out_specs=hs, check_rep=False)
+                   out_specs=hs, check_vma=False)
     return fn(*operands)
 
 
@@ -338,25 +343,28 @@ def _model_only(spec: P, ctx: TPContext) -> P:
     return P(*(keep(e) for e in spec))
 
 
+def param_shardings(axes_tree, params, ctx: TPContext):
+    """The NamedSharding of every param in the layout tp.linear's in_specs
+    expect: model-axis dims sharded, everything else replicated. ``params``
+    may hold arrays or shapes (``jax.eval_shape``)."""
+    def one(axes_leaf, param):
+        spec = (_model_only(ctx.rules.spec(tuple(axes_leaf), param.shape),
+                            ctx)
+                if is_axis_leaf(axes_leaf) else P())
+        return NamedSharding(ctx.mesh, spec)
+
+    return jax.tree_util.tree_map(one, axes_tree, params,
+                                  is_leaf=is_axis_leaf)
+
+
 def shard_params(params, axes_tree, ctx: Optional[TPContext]):
-    """device_put every param in the layout tp.linear's in_specs expect:
-    model-axis dims sharded, everything else replicated. Placement is a
-    performance property only — shard_map slices the global value per its
-    specs regardless — but resident placement avoids re-distributing every
-    weight on every step."""
+    """device_put every param in its :func:`param_shardings` layout.
+    Placement is a performance property only — shard_map slices the global
+    value per its specs regardless — but resident placement avoids
+    re-distributing every weight on every step."""
     if ctx is None:
         return params
-
-    def one(axes_leaf, param):
-        if not is_axis_leaf(axes_leaf) or not hasattr(param, "shape"):
-            return param
-        spec = _model_only(
-            ctx.rules.spec(tuple(axes_leaf), param.shape), ctx)
-        return jax.device_put(param, NamedSharding(ctx.mesh, spec))
-
-    return jax.tree_util.tree_map(
-        lambda a, p: one(a, p), axes_tree, params,
-        is_leaf=is_axis_leaf)
+    return jax.device_put(params, param_shardings(axes_tree, params, ctx))
 
 
 _KV_LEAVES = ("k", "v", "kp", "vp")

@@ -45,14 +45,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _CompilerParams = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.kernel_contracts import (KernelContract, OperandSpec,
                                              Precondition, register_contract,
@@ -63,33 +56,31 @@ NEG_INF = -1e30
 # ---------------------------------------------------------------------------
 # The dataflow mapping, stated once: these index maps are handed to
 # pl.BlockSpec below AND cited by the registered KernelContract, so the
-# static checker verifies the very callables the kernel executes.
+# static checker verifies the very callables the kernel executes. The
+# trailing ``*_`` takes the scalar-prefetch ref (kv_valid_len), which no map
+# reads; the checker calls them with the grid indices alone.
 # ---------------------------------------------------------------------------
 
 ATTN_DIMENSION_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
 
 
-def _qpos_index_map(b, h, i, j):
+def _qpos_index_map(b, h, i, j, *_):
     return (b, i, 0)
 
 
-def _kvlen_index_map(b, h, i, j):
-    return (b, 0)
-
-
-def _q_index_map(b, h, i, j):
+def _q_index_map(b, h, i, j, *_):
     return (b, h, i, 0)
 
 
 def _make_kv_index_map(rep: int):
     """K/V fetch under GQA: query head h reads kv head h // rep — the
     BlockSpec expression of grouped heads (no HBM repeat)."""
-    def _kv_index_map(b, h, i, j):
+    def _kv_index_map(b, h, i, j, *_):
         return (b, h // rep, j, 0)
     return _kv_index_map
 
 
-def _o_index_map(b, h, i, j):
+def _o_index_map(b, h, i, j, *_):
     return (b, h, i, 0)
 
 
@@ -116,6 +107,8 @@ def flash_attention_contract(*, B, H, Hkv, Sq, Sk, D, Dv,
     along grid axis 3 (the key stream) — the declared reduction axis.
     K/V coverage under GQA is partial by construction (each kv head is
     fetched rep times; every (b, hkv, j) block is still touched).
+    ``kv_valid_len`` is a scalar-prefetch operand (SMEM, read whole), so it
+    has no block geometry to check.
     """
     rep = H // Hkv if Hkv and H % Hkv == 0 else 1
     bq, bk = min(block_q, Sq), min(block_k, Sk)
@@ -125,8 +118,6 @@ def flash_attention_contract(*, B, H, Hkv, Sq, Sk, D, Dv,
     operands = (
         OperandSpec("q_positions", "input", (B, nq, 1), (1, bq, 1),
                     _qpos_index_map, expected_blocks=None),
-        OperandSpec("kv_valid_len", "input", (B, 1), (1, 1),
-                    _kvlen_index_map),
         OperandSpec("q", "input", (B, H, nq, 1), (1, 1, bq, D),
                     _q_index_map),
         OperandSpec("k", "input", (B, Hkv, nk, 1), (1, 1, bk, D),
@@ -196,10 +187,11 @@ def attention_block_flush(l_ref, acc_ref, dtype):
     return (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(dtype)
 
 
-def _kernel(qpos_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
+def _kernel(kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref,
             m_ref, l_ref, acc_ref, *,
             scale: float, causal: bool, soft_cap: Optional[float],
             bq: int, bk: int, nk: int):
+    b = pl.program_id(0)
     ik = pl.program_id(3)
 
     @pl.when(ik == 0)
@@ -207,7 +199,7 @@ def _kernel(qpos_ref, kvlen_ref, q_ref, k_ref, v_ref, o_ref,
         attention_block_init(m_ref, l_ref, acc_ref)
 
     qpos = qpos_ref[0]                                    # (bq, 1) int32
-    kvlen = kvlen_ref[0, 0]                               # scalar int32
+    kvlen = kvlen_ref[b]                                  # scalar int32
     # Skip key blocks no row of this q block can see: past every valid key,
     # or (causal) strictly in the future of the furthest query position.
     run = ik * bk < kvlen
@@ -276,36 +268,36 @@ def flash_attention(
     nq, nk = Sq_p // bq, Sk_p // bk
 
     # (B, Sq_p, 1) so the kernel reads a (bq, 1) tile that broadcasts
-    # directly against the (bq, bk) score tile; (B, 1) for the scalar len.
+    # directly against the (bq, bk) score tile. The per-row valid length is
+    # scalar-prefetched into SMEM: a (1, 1) VMEM block of a (B, 1) array
+    # breaks the TPU's (8, 128) block-tiling rule.
     qpos_in = q_positions[..., None]
-    kvlen_in = kv_valid_len[:, None]
 
-    grid = (B, H, nq, nk)
     kernel = functools.partial(_kernel, scale=scale, causal=causal,
                                soft_cap=soft_cap, bq=bq, bk=bk, nk=nk)
-    kwargs = {}
-    if _CompilerParams is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=ATTN_DIMENSION_SEMANTICS)
     kv_index_map = _make_kv_index_map(rep)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,              # kv_valid_len
+        grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, 1), _qpos_index_map),
-            pl.BlockSpec((1, 1), _kvlen_index_map),
             pl.BlockSpec((1, 1, bq, D), _q_index_map),
             pl.BlockSpec((1, 1, bk, D), kv_index_map),
             pl.BlockSpec((1, 1, bk, Dv), kv_index_map),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, Dv), _o_index_map),
-        out_shape=jax.ShapeDtypeStruct((B, H, Sq_p, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq_p, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=ATTN_DIMENSION_SEMANTICS),
         interpret=interpret,
-        **kwargs,
-    )(qpos_in, kvlen_in, q, k, v)
+    )(kv_valid_len, qpos_in, q, k, v)
     return out[:, :, :Sq]

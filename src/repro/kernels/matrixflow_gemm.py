@@ -25,14 +25,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU compiler params: name moved across jax versions
-    from jax.experimental.pallas import tpu as pltpu
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _CompilerParams = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.kernel_contracts import (KernelContract, OperandSpec,
                                              Precondition, register_contract,
@@ -210,11 +203,6 @@ def matrixflow_gemm_block_major(
                           (jnp.float32 if fused else acc_dtype))
 
     grid = (nbm, nbn, nbk)
-    kwargs = {}
-    if _CompilerParams is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=GEMM_DIMENSION_SEMANTICS,
-        )
     scratch = [pltpu.VMEM((bm, bn), acc_dtype)]
 
     in_specs = [
@@ -248,8 +236,9 @@ def matrixflow_gemm_block_major(
         out_specs=pl.BlockSpec((1, 1, bm, bn), _c_index_map),
         out_shape=jax.ShapeDtypeStruct((nbm, nbn, bm, bn), out_dtype),
         scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=GEMM_DIMENSION_SEMANTICS),
         interpret=interpret,
-        **kwargs,
     )
     return call(*operands)
 

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import layout as L
+from repro.core.plan import on_tpu
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.matrixflow_gemm import (matrixflow_gemm,
@@ -23,18 +24,11 @@ from repro.kernels.matrixflow_gemm import (matrixflow_gemm,
 from repro.kernels.ssd_scan import ssd_scan
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
 def gemm(a: jax.Array, b: jax.Array, *, mode: str = "dm",
          out_dtype: Optional[jnp.dtype] = None,
          impl: Optional[str] = None) -> jax.Array:
     """MatrixFlow GEMM. impl: None (auto) | 'pallas' | 'interpret' | 'ref'."""
-    impl = impl or ("pallas" if _on_tpu() else "interpret")
+    impl = impl or ("pallas" if on_tpu() else "interpret")
     if impl == "ref":
         return ref.matmul_ref(a, b, out_dtype)
     return matrixflow_gemm(a, b, mode=mode, out_dtype=out_dtype,
@@ -46,7 +40,7 @@ def gemm_preformatted(a_bm: jax.Array, b_bm: jax.Array, *, blk: L.BlockLayout,
                       impl: Optional[str] = None) -> jax.Array:
     """Deploy path: operands already block-major (weights formatted once at
     load; activations produced block-major by the previous GEMM — Fig. 5)."""
-    impl = impl or ("pallas" if _on_tpu() else "interpret")
+    impl = impl or ("pallas" if on_tpu() else "interpret")
     return matrixflow_gemm_block_major(a_bm, b_bm, blk=blk,
                                        out_dtype=out_dtype,
                                        interpret=(impl == "interpret"))
@@ -64,7 +58,7 @@ def mha(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
     and cache-length semantics (see kernels/flash_attention.py). impl 'ref'
     uses the pure-jnp oracle; otherwise the Pallas flash kernel (interpret
     mode off-TPU)."""
-    impl = impl or ("pallas" if _on_tpu() else "interpret")
+    impl = impl or ("pallas" if on_tpu() else "interpret")
     if impl == "ref":
         return ref.mha_ref(q, k, v, causal=causal, scale=scale,
                            soft_cap=soft_cap, q_positions=q_positions,
@@ -82,7 +76,7 @@ def ssd(x: jax.Array, dt: jax.Array, A: jax.Array, Bc: jax.Array,
         Cc: jax.Array, *, chunk: int = 128,
         impl: Optional[str] = None) -> jax.Array:
     """Chunked SSD scan (B, S, H, P). impl as in mha()."""
-    impl = impl or ("pallas" if _on_tpu() else "interpret")
+    impl = impl or ("pallas" if on_tpu() else "interpret")
     if impl == "ref":
         return ref.ssd_ref(x, dt, A, Bc, Cc)
     return ssd_scan(x, dt, A, Bc, Cc, chunk=chunk,

@@ -50,14 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _CompilerParams = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.kernel_contracts import (KernelContract, OperandSpec,
                                              Precondition, register_contract,
@@ -72,6 +65,13 @@ from repro.kernels.flash_attention import (ATTN_DIMENSION_SEMANTICS,
 # The dataflow mapping, stated once. All maps take the scalar-prefetch
 # signature (b, h, i, j, bt, kvl); the registered contract binds a concrete
 # block table and hands the checker the very callables pallas_call runs.
+#
+# The kernel sees heads folded into the lane axis: q/o as (B, Sq, H*D) and
+# the pools as (P, ps, Hkv*D) — free reshapes of the model and pool
+# layouts. Head h is then the h-th D-wide lane block, so every block's last
+# two dims are (rows, D): legal under the TPU's (8, 128) tiling rule when D
+# is a multiple of 128, where a (.., 1, D) head slice on the second-minor
+# axis is not.
 # ---------------------------------------------------------------------------
 
 def _pg_qpos_index_map(b, h, i, j, bt, kvl):
@@ -79,7 +79,7 @@ def _pg_qpos_index_map(b, h, i, j, bt, kvl):
 
 
 def _pg_q_index_map(b, h, i, j, bt, kvl):
-    return (b, i, h, 0)
+    return (b, i, h)
 
 
 def _make_page_index_map(rep: int):
@@ -87,24 +87,25 @@ def _make_page_index_map(rep: int):
     physical page ``bt[b, j]``; GQA folds the query head to kv head
     h // rep. The block-table entry IS the index."""
     def _page_index_map(b, h, i, j, bt, kvl):
-        return (bt[b, j], 0, h // rep, 0)
+        return (bt[b, j], 0, h // rep)
     return _page_index_map
 
 
-def _make_page_scale_index_map(rep: int):
-    """Each int8 page's (1, 1) fp32 scale rides the same indirection."""
-    def _page_scale_index_map(b, h, i, j, bt, kvl):
-        return (bt[b, j], h // rep)
-    return _page_scale_index_map
+def _page_scale_index_map(b, h, i, j, bt, kvl):
+    """Each int8 page's (1, 1, Hkv) fp32 scale row rides the same
+    indirection; the kernel picks the kv head's lane."""
+    return (bt[b, j], 0, 0)
 
 
 def _pg_o_index_map(b, h, i, j, bt, kvl):
-    return (b, i, h, 0)
+    return (b, i, h)
 
 
-def paged_preconditions(H, Hkv, k_pages_shape, v_pages_shape, nb):
+def paged_preconditions(H, Hkv, k_pages_shape, v_pages_shape, nb, *,
+                        interpret: bool = False):
     """Structured entry guards shared between runtime and static checker."""
     P, ps, Hkv_v = v_pages_shape[0], v_pages_shape[1], v_pages_shape[2]
+    D, Dv = k_pages_shape[-1], v_pages_shape[-1]
     return (
         Precondition.check(
             "GQA head divisibility", Hkv > 0 and H % Hkv == 0,
@@ -117,6 +118,13 @@ def paged_preconditions(H, Hkv, k_pages_shape, v_pages_shape, nb):
             f"{tuple(v_pages_shape)} disagree on (P, page_size, Hkv); the "
             f"pools must be allocated as one paged cache"),
         Precondition.check(
+            "TPU lane tiling",
+            interpret or (D % 128 == 0 and Dv % 128 == 0) or H == Hkv == 1,
+            f"head dims D={D}, Dv={Dv} with H={H}, Hkv={Hkv}: compiled for "
+            f"a TPU, heads fold into the lane axis in D-wide blocks, which "
+            f"must be multiples of 128 unless there is a single head "
+            f"(a head-major pool lifts this, ROADMAP Speed 3)"),
+        Precondition.check(
             "populated block table", nb > 0,
             f"block table has {nb} blocks: the grid's key axis would have "
             f"zero extent and the flush step would never run (the caller "
@@ -127,7 +135,8 @@ def paged_preconditions(H, Hkv, k_pages_shape, v_pages_shape, nb):
 @register_contract("paged_attention")
 def paged_attention_contract(*, B, Sq, H, Hkv, D, Dv, P, page_size,
                              block_tables, block_q: int = 128,
-                             quantized: bool = False) -> KernelContract:
+                             quantized: bool = False,
+                             interpret: bool = False) -> KernelContract:
     """Contract of :func:`paged_attention` for one concrete block table.
 
     ``block_tables`` is the actual (B, nb) int array: the checker evaluates
@@ -135,6 +144,7 @@ def paged_attention_contract(*, B, Sq, H, Hkv, D, Dv, P, page_size,
     page indices surface as bounds violations and pool coverage narrows to
     exactly the pages the table references (distractor pages are dead by
     design). Output o is revisited along grid axis 3 (the key stream).
+    ``interpret`` lifts the TPU's lane-tiling precondition.
     """
     bt = np.asarray(block_tables, dtype=np.int64)
     nb = bt.shape[1] if bt.ndim == 2 else 0
@@ -143,36 +153,36 @@ def paged_attention_contract(*, B, Sq, H, Hkv, D, Dv, P, page_size,
     bq = min(block_q, Sq)
     nq = (Sq + (-Sq) % bq) // bq
     page_map = _make_page_index_map(rep)
-    scale_map = _make_page_scale_index_map(rep)
 
     def bind(m):
         # close over the concrete table, exactly like PrefetchScalarGridSpec
         return lambda b, h, i, j: m(b, h, i, j, bt, None)
 
     referenced = frozenset(
-        (int(bt[b, j]), 0, hk, 0)
+        (int(bt[b, j]), 0, hk)
         for b in range(bt.shape[0]) for j in range(nb)
         for hk in range(Hkv))
     operands = [
         OperandSpec("q_positions", "input", (B, nq, 1), (1, bq, 1),
                     bind(_pg_qpos_index_map)),
-        OperandSpec("q", "input", (B, nq, H, 1), (1, bq, 1, D),
+        OperandSpec("q", "input", (B, nq, H), (1, bq, D),
                     bind(_pg_q_index_map)),
-        OperandSpec("k_pages", "input", (P, 1, Hkv, 1), (1, ps, 1, D),
+        OperandSpec("k_pages", "input", (P, 1, Hkv), (1, ps, D),
                     bind(page_map), expected_blocks=referenced),
-        OperandSpec("v_pages", "input", (P, 1, Hkv, 1), (1, ps, 1, Dv),
+        OperandSpec("v_pages", "input", (P, 1, Hkv), (1, ps, Dv),
                     bind(page_map), expected_blocks=referenced),
-        OperandSpec("o", "output", (B, nq, H, 1), (1, bq, 1, Dv),
+        OperandSpec("o", "output", (B, nq, H), (1, bq, Dv),
                     bind(_pg_o_index_map), reduction_axes=(3,)),
     ]
     if quantized:
-        scale_blocks = frozenset(
-            (p, hk) for (p, _z, hk, _w) in referenced)
+        scale_blocks = frozenset((p, 0, 0) for (p, _z, _hk) in referenced)
         operands += [
-            OperandSpec("k_scales", "input", (P, Hkv), (1, 1),
-                        bind(scale_map), expected_blocks=scale_blocks),
-            OperandSpec("v_scales", "input", (P, Hkv), (1, 1),
-                        bind(scale_map), expected_blocks=scale_blocks),
+            OperandSpec("k_scales", "input", (P, 1, 1), (1, 1, Hkv),
+                        bind(_page_scale_index_map),
+                        expected_blocks=scale_blocks),
+            OperandSpec("v_scales", "input", (P, 1, 1), (1, 1, Hkv),
+                        bind(_page_scale_index_map),
+                        expected_blocks=scale_blocks),
         ]
     k_shape = (P, ps, Hkv, D)
     v_shape = (P, ps, Hkv, Dv)
@@ -181,13 +191,14 @@ def paged_attention_contract(*, B, Sq, H, Hkv, D, Dv, P, page_size,
         grid=(B, H, nq, nb),
         operands=tuple(operands),
         dimension_semantics=ATTN_DIMENSION_SEMANTICS,
-        preconditions=paged_preconditions(H, Hkv, k_shape, v_shape, nb),
+        preconditions=paged_preconditions(H, Hkv, k_shape, v_shape, nb,
+                                          interpret=interpret),
         description="block-table paged flash attention (scalar prefetch)")
 
 
 def _kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
             scale: float, causal: bool, soft_cap: Optional[float],
-            bq: int, ps: int, nb: int, quantized: bool):
+            bq: int, ps: int, nb: int, rep: int, quantized: bool):
     # rest is [ks_ref, vs_ref,] o_ref, m_ref, l_ref, acc_ref — the scale
     # operands exist only on the int8 path (pallas passes refs positionally
     # in in_specs order, then outputs, then scratch).
@@ -196,6 +207,7 @@ def _kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
+    hk = pl.program_id(1) // rep                          # kv head
     ij = pl.program_id(3)                                 # logical key block
 
     @pl.when(ij == 0)
@@ -217,21 +229,29 @@ def _kernel(bt_ref, kvlen_ref, qpos_ref, q_ref, k_ref, v_ref, *rest,
         # masking arithmetic — the numerics are flash_attention.py's
         # recurrence, shared verbatim.
         cols = ij * ps + jax.lax.broadcasted_iota(jnp.int32, (bq, ps), 1)
-        kblk = k_ref[0, :, 0]
-        vblk = v_ref[0, :, 0]
+        kblk = k_ref[0]
+        vblk = v_ref[0]
         if quantized:
             # dequantize the int8 page in-register: the HBM→VMEM stream
             # stayed int8, the recurrence below runs fp32 as always. The
-            # scale tile is (1, 1) — this page, this kv head.
-            kblk = kblk.astype(jnp.float32) * ks_ref[0, 0]
-            vblk = vblk.astype(jnp.float32) * vs_ref[0, 0]
-        attention_block_step(q_ref[0, :, 0], kblk, vblk,
+            # scale block is this page's (1, Hkv) row; a lane mask picks
+            # this kv head's scale without a dynamic lane index.
+            pick = jax.lax.broadcasted_iota(
+                jnp.int32, ks_ref.shape[1:], 1) == hk
+
+            def head_scale(s_ref):
+                return jnp.sum(jnp.where(pick, s_ref[0], 0.0), axis=1,
+                               keepdims=True)              # (1, 1)
+
+            kblk = kblk.astype(jnp.float32) * head_scale(ks_ref)
+            vblk = vblk.astype(jnp.float32) * head_scale(vs_ref)
+        attention_block_step(q_ref[0], kblk, vblk,
                              cols, qpos, kvlen, m_ref, l_ref, acc_ref,
                              scale=scale, causal=causal, soft_cap=soft_cap)
 
     @pl.when(ij == nb - 1)
     def _flush():
-        o_ref[0, :, 0] = attention_block_flush(l_ref, acc_ref, o_ref.dtype)
+        o_ref[0] = attention_block_flush(l_ref, acc_ref, o_ref.dtype)
 
 
 @functools.partial(
@@ -262,16 +282,21 @@ def paged_attention(
 
     With int8 pools, ``kv_scales`` must carry the per-page-per-head fp32
     ``(k_scales, v_scales)`` arrays of shape (P, Hkv) (docs/quant.md
-    #kv-pages); the kernel fetches each page's (1, 1) scale alongside the
+    #kv-pages); the kernel fetches each page's scale row alongside the
     page and dequantizes in-register, so the HBM stream stays int8.
+
+    Compiled for a TPU, D and Dv must be multiples of 128 unless there is
+    a single head (see the layout note above the index maps); other head
+    dims raise ValueError.
     """
     B, Sq, H, D = q.shape
     P, ps, Hkv, Dv = v_pages.shape
     nb_early = block_tables.shape[1]
-    pre = paged_preconditions(H, Hkv, k_pages.shape, v_pages.shape, nb_early)
-    # nb == 0 is legal here (short-circuited below); the other two guards
-    # are hard errors shared verbatim with the static contract.
-    require(*pre[:2])
+    pre = paged_preconditions(H, Hkv, k_pages.shape, v_pages.shape, nb_early,
+                              interpret=interpret)
+    # nb == 0 is legal here (short-circuited below); the other guards are
+    # hard errors shared verbatim with the static contract.
+    require(*pre[:-1])
     quantized = k_pages.dtype == jnp.int8
     if quantized != (v_pages.dtype == jnp.int8):
         raise ValueError(
@@ -326,45 +351,45 @@ def paged_attention(
 
     kernel = functools.partial(_kernel, scale=scale, causal=causal,
                                soft_cap=soft_cap, bq=bq, ps=ps, nb=nb,
-                               quantized=quantized)
+                               rep=rep, quantized=quantized)
     page_index_map = _make_page_index_map(rep)
     in_specs = [
         pl.BlockSpec((1, bq, 1), _pg_qpos_index_map),
-        pl.BlockSpec((1, bq, 1, D), _pg_q_index_map),
+        pl.BlockSpec((1, bq, D), _pg_q_index_map),
         # the paged indirection: the block table entry IS the index
-        pl.BlockSpec((1, ps, 1, D), page_index_map),
-        pl.BlockSpec((1, ps, 1, Dv), page_index_map),
+        pl.BlockSpec((1, ps, D), page_index_map),
+        pl.BlockSpec((1, ps, Dv), page_index_map),
     ]
-    operands = [block_tables, kv_valid_len, qpos_in, q, k_pages, v_pages]
+    # heads folded into lanes (free reshapes; see the index-map note)
+    operands = [block_tables, kv_valid_len, qpos_in,
+                q.reshape(B, Sq_p, H * D), k_pages.reshape(P, ps, Hkv * D),
+                v_pages.reshape(P, ps, Hkv * Dv)]
     if quantized:
-        # each page's scale rides the same block-table indirection as the
-        # page itself: one (1, 1) fp32 element per (page, kv head).
-        scale_spec = pl.BlockSpec((1, 1), _make_page_scale_index_map(rep))
+        # each page's scales ride the same block-table indirection as the
+        # page itself: one (1, Hkv) fp32 row per page.
+        scale_spec = pl.BlockSpec((1, 1, Hkv), _page_scale_index_map)
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scales, v_scales]
+        operands += [k_scales.reshape(P, 1, Hkv), v_scales.reshape(P, 1, Hkv)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,              # block_tables, kv_valid_len
         grid=(B, H, nq, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, 1, Dv), _pg_o_index_map),
+        out_specs=pl.BlockSpec((1, bq, Dv), _pg_o_index_map),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, Dv), jnp.float32),
         ],
     )
-    kwargs = {}
-    if _CompilerParams is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=ATTN_DIMENSION_SEMANTICS)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Sq_p, H, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Sq_p, H * Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=ATTN_DIMENSION_SEMANTICS),
         interpret=interpret,
-        **kwargs,
     )(*operands)
-    return out[:, :Sq]
+    return out.reshape(B, Sq_p, H, Dv)[:, :Sq]
 
 
 def gather_pages(pages: jax.Array, block_tables: jax.Array,

@@ -26,14 +26,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None)
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _CompilerParams = None
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.analysis.kernel_contracts import (KernelContract, OperandSpec,
                                              Precondition, register_contract)
@@ -54,7 +47,7 @@ def _x_index_map(b, h, k):
 
 
 def _a_index_map(b, h, k):
-    return (b, h, k, 0)
+    return (b, h, k, 0, 0)
 
 
 def _bc_index_map(b, h, k):
@@ -90,7 +83,7 @@ def ssd_contract(*, B, S, H, P, N, chunk: int = 128) -> KernelContract:
     operands = (
         OperandSpec("x", "input", (B, H, nc, 1, 1), (1, 1, 1, Q, P),
                     _x_index_map),
-        OperandSpec("a", "input", (B, H, nc, 1), (1, 1, 1, Q),
+        OperandSpec("a", "input", (B, H, nc, 1, 1), (1, 1, 1, 1, Q),
                     _a_index_map),
         OperandSpec("Bc", "input", (B, nc, 1, 1), (1, 1, Q, N),
                     _bc_index_map),
@@ -116,8 +109,8 @@ def ssd_contract(*, B, S, H, P, N, chunk: int = 128) -> KernelContract:
 
 def _kernel(x_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *, nc: int, Q: int):
     """One (batch, head, chunk) step. Block shapes:
-    x (1,1,Q,P) pre-scaled by dt; a (1,1,Q) per-step log-decay dt·A;
-    b/c (1,1,Q,N); carried state scratch (P,N) fp32."""
+    x (1,1,1,Q,P) pre-scaled by dt; a (1,1,1,1,Q) per-step log-decay dt·A
+    as a lane row; b/c (1,1,Q,N); carried state scratch (P,N) fp32."""
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -125,16 +118,26 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *, nc: int, Q: int):
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[0, 0, 0].astype(jnp.float32)       # (Q, P)  = dt_j * x_j
-    a = a_ref[0, 0, 0].astype(jnp.float32)       # (Q,)
+    a = a_ref[0, 0, 0].astype(jnp.float32)       # (1, Q)
     bmat = b_ref[0, 0].astype(jnp.float32)       # (Q, N)
     cmat = c_ref[0, 0].astype(jnp.float32)       # (Q, N)
 
-    cum = jnp.cumsum(a)                          # (Q,)
-    # L[i,j] = exp(cum_i - cum_j) for j <= i  (segment-sum decay)
-    diff = cum[:, None] - cum[None, :]
     ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
-    Lmask = jnp.where(jj <= ii, jnp.exp(diff), 0.0)
+    causal = jj <= ii
+    # In-chunk cumulative sum as a lower-triangular matmul (the TPU
+    # compiler has no cumsum lowering), once as a column and once as a row:
+    # cum_i = sum_{j <= i} a_j.
+    lower = causal.astype(jnp.float32)
+    nt = (((1,), (1,)), ((), ()))
+    hi = jax.lax.Precision.HIGHEST
+    cum_col = jax.lax.dot_general(lower, a, nt, precision=hi,
+                                  preferred_element_type=jnp.float32)  # (Q,1)
+    cum_row = jax.lax.dot_general(a, lower, nt, precision=hi,
+                                  preferred_element_type=jnp.float32)  # (1,Q)
+    total = jnp.sum(a, axis=1, keepdims=True)    # (1, 1) = cum_{Q-1}
+    # L[i,j] = exp(cum_i - cum_j) for j <= i  (segment-sum decay)
+    Lmask = jnp.where(causal, jnp.exp(cum_col - cum_row), 0.0)
 
     # intra-chunk: Y = (L ∘ C Bᵀ) (dt x)
     cb = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
@@ -145,14 +148,14 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, o_ref, state_ref, *, nc: int, Q: int):
     # inter-chunk: Y += diag(exp(cum)) C h_prevᵀ        h_prev: (P,N)
     y_inter = jax.lax.dot_general(
         cmat, state_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * jnp.exp(cum)[:, None]
+        preferred_element_type=jnp.float32) * jnp.exp(cum_col)
 
     # state update: h = exp(sum a) h_prev + (decay_end ∘ x)ᵀ B
-    decay_end = jnp.exp(cum[-1] - cum)           # (Q,)
-    xw = x * decay_end[:, None]                  # (Q, P)
+    decay_end = jnp.exp(total - cum_col)         # (Q, 1)
+    xw = x * decay_end                           # (Q, P)
     dstate = jax.lax.dot_general(xw, bmat, (((0,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (P,N)
-    state_ref[...] = state_ref[...] * jnp.exp(cum[-1]) + dstate
+    state_ref[...] = state_ref[...] * jnp.exp(total) + dstate
 
     o_ref[0, 0, 0] = (y_intra + y_inter).astype(o_ref.dtype)
 
@@ -181,30 +184,29 @@ def ssd_scan(
 
     # head-major layouts: (B, H, nc, Q, ·)
     xq = dtx.reshape(B, nc, Q, H, P).transpose(0, 3, 1, 2, 4)
-    aq = a.reshape(B, nc, Q, H).transpose(0, 3, 1, 2)
+    # a as a (1, Q) lane row per chunk: a (1, Q) block of (nc, Q) would
+    # break the TPU's (8, 128) block-tiling rule on the chunk axis
+    aq = a.reshape(B, nc, Q, H).transpose(0, 3, 1, 2)[:, :, :, None, :]
     bq = Bc.astype(jnp.float32).reshape(B, nc, Q, N)
     cq = Cc.astype(jnp.float32).reshape(B, nc, Q, N)
 
     grid = (B, H, nc)
     kernel = functools.partial(_kernel, nc=nc, Q=Q)
-    kwargs = {}
-    if _CompilerParams is not None and not interpret:
-        kwargs["compiler_params"] = _CompilerParams(
-            dimension_semantics=SSD_DIMENSION_SEMANTICS)
     out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, Q, P), _x_index_map),
-            pl.BlockSpec((1, 1, 1, Q), _a_index_map),
+            pl.BlockSpec((1, 1, 1, 1, Q), _a_index_map),
             pl.BlockSpec((1, 1, Q, N), _bc_index_map),
             pl.BlockSpec((1, 1, Q, N), _bc_index_map),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, Q, P), _y_index_map),
         out_shape=jax.ShapeDtypeStruct((B, H, nc, Q, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=SSD_DIMENSION_SEMANTICS),
         interpret=interpret,
-        **kwargs,
     )(xq, aq, bq, cq)
     # (B, H, nc, Q, P) → (B, S, H, P)
     return out.reshape(B, H, S, P).transpose(0, 2, 1, 3)

@@ -6,13 +6,22 @@ jax device state — the dry-run must set XLA_FLAGS before first jax init.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis Auto: sharding is propagated by the
+    compiler from the constraints the code states (``shard()``, shard_map
+    specs). The one place that decides the mesh's axis types."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; multi_pod adds a leading 2-pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(*, model: int = 1):
@@ -33,4 +42,4 @@ def make_host_mesh(*, model: int = 1):
             f"not divisible by model={model}; pick a factor of {n} (or "
             f"force host devices via XLA_FLAGS="
             f"--xla_force_host_platform_device_count=N before jax init)")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

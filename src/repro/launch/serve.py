@@ -15,6 +15,8 @@ import numpy as np
 
 from repro.configs.registry import get_config, get_smoke_config
 from repro.core.plan import AttentionPolicy, GemmPolicy
+from repro.distributed import tp as TP
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
 from repro.obs import NULL_OBS, Observability, Timer
@@ -35,7 +37,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--gemm-backend", default="auto",
-                    help="GEMM backend (auto|xla|pallas|pallas_interpret|"
+                    help="GEMM backend (auto|xla|pallas|"
                          "blockflow|<registered>)")
     ap.add_argument("--gemm-mode", default="auto",
                     choices=["auto", "dc", "dm"],
@@ -46,8 +48,8 @@ def main(argv=None):
                     help="int8 → quantized W8A8 GEMM route (docs/quant.md); "
                          "with --pack-weights the int8 blocks stay resident")
     ap.add_argument("--attn-backend", default="auto",
-                    help="attention backend (auto|fused|fused_interpret|"
-                         "unfused|paged|paged_interpret|<registered>); "
+                    help="attention backend (auto|fused|unfused|paged|"
+                         "<registered>); "
                          "fused = the offset-aware flash kernel for prefill "
                          "AND decode (docs/attention.md); paged = the "
                          "block-table paged KV cache with page-bound "
@@ -114,6 +116,7 @@ def main(argv=None):
                          "registry snapshot (counters/gauges/histograms) "
                          "to PATH as JSON")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     policy = GemmPolicy(backend=args.gemm_backend, mode=args.gemm_mode)
@@ -121,7 +124,7 @@ def main(argv=None):
                            page_size=args.page_size)
     if args.kv_dtype and not args.attn_backend.startswith("paged"):
         ap.error("--kv-dtype requires a paged attention backend "
-                 "(--attn-backend paged|paged_interpret)")
+                 "(--attn-backend paged)")
     mesh = make_host_mesh(model=args.tp) if args.tp > 1 else None
     scheduler = (Scheduler(prefill_chunk=args.prefill_chunk)
                  if args.prefill_chunk else None)
@@ -150,8 +153,13 @@ def main(argv=None):
               f"prefill_chunk={args.prefill_chunk or 'whole-prompt'}")
     elif args.prefix_cache:
         ap.error("--prefix-cache requires a paged attention backend "
-                 "(--attn-backend paged|paged_interpret)")
-    params, axes = T.init_model(jax.random.PRNGKey(args.seed), cfg)
+                 "(--attn-backend paged)")
+    key = jax.random.PRNGKey(args.seed)
+    if mesh is None:
+        params, axes = T.init_model(key, cfg)
+    else:   # created in place, shard by shard: a TP model may fit no chip
+        params, axes = T.init_sharded_model(
+            key, cfg, TP.make_context(mesh, None, cfg))
     engine = ServingEngine(cfg, params, sc, axes=axes)
 
     rng = np.random.default_rng(args.seed)

@@ -136,4 +136,4 @@ def make_rules(cfg: ModelConfig, mesh, shape: Optional[ShapeCell] = None,
         overrides.setdefault("act_kv_seq", "data")
     if extra_overrides:
         overrides.update(extra_overrides)
-    return shd.ShardingRules(mesh, overrides)
+    return shd.ShardingRules(mesh, overrides, cfg.head_units())

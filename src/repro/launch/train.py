@@ -25,6 +25,7 @@ from repro.core.plan import GemmPolicy
 from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.distributed import sharding as shd
 from repro.launch import steps as ST
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import transformer as T
 from repro.obs import Timer
@@ -51,12 +52,13 @@ def main(argv=None):
     ap.add_argument("--production-mesh", action="store_true",
                     help="16x16 mesh (requires 256 devices)")
     ap.add_argument("--gemm-backend", default="auto",
-                    help="GEMM backend (auto|xla|pallas|pallas_interpret|"
+                    help="GEMM backend (auto|xla|pallas|"
                          "blockflow|<registered>)")
     ap.add_argument("--gemm-mode", default="auto",
                     choices=["auto", "dc", "dm"],
                     help="paper access mode; auto = per-shape sysmodel pick")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = (make_production_mesh() if args.production_mesh

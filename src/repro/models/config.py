@@ -97,6 +97,11 @@ class ModelConfig:
     def overrides_dict(self) -> Dict[str, Optional[str]]:
         return dict(self.sharding_overrides)
 
+    def head_units(self) -> Dict[str, int]:
+        """Heads per fused attention projection dim: the units a tensor-
+        parallel split of that dim must keep whole."""
+        return {"heads": self.n_heads, "kv_heads": self.n_kv_heads}
+
 
 def reduced(cfg: ModelConfig, **kw) -> ModelConfig:
     """Smoke-test shrink: same family/topology, tiny dims."""

@@ -128,6 +128,22 @@ def init_model(key, cfg: ModelConfig) -> Tuple[Dict, Dict]:
     return p, a
 
 
+def init_sharded_model(key, cfg: ModelConfig,
+                       tpctx: TP.TPContext) -> Tuple[Dict, Dict]:
+    """:func:`init_model` with every weight created in its TP placement
+    (:func:`repro.distributed.tp.param_shardings`): the jitted init writes
+    each shard on its own device, so no device ever holds the whole model —
+    the only way to build one that does not fit a single chip. The values
+    equal :func:`init_model`'s (the PRNG is partitionable)."""
+    axes = model_axes(cfg)
+
+    def init(k):
+        return init_model(k, cfg)[0]
+
+    shardings = TP.param_shardings(axes, jax.eval_shape(init, key), tpctx)
+    return jax.jit(init, out_shardings=shardings)(key), axes
+
+
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------

@@ -67,7 +67,7 @@ from repro.serving.kv_pool import BlockTable, PagePool
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.scheduler import RequestView, Scheduler
 
-PAGED_BACKENDS = ("paged", "paged_interpret")
+PAGED_BACKENDS = ("paged",)
 
 
 def _next_pow2(n: int) -> int:
@@ -254,7 +254,7 @@ class ServingEngine:
     """Greedy/temperature sampling with slot-based continuous batching.
 
     With a paged attention policy (``ServeConfig.attention`` backend
-    "paged"/"paged_interpret") the engine runs **memory-bound continuous
+    "paged") the engine runs **memory-bound continuous
     batching**: submit() returns a *request id*, admission holds while free
     pages cover the prompt, decode grows block tables on demand, and pool
     exhaustion preempts a scheduler-chosen victim into a wait queue from
@@ -288,8 +288,7 @@ class ServingEngine:
                     "resident packed/quantized weights — block-major "
                     "PackedWeight pytrees would need per-shard re-packing; "
                     "drop pack_weights/weight_dtype or the mesh")
-            self.tp = TP.make_context(sc.mesh, sc.sharding,
-                                      cfg.overrides_dict())
+            self.tp = TP.make_context(sc.mesh, sc.sharding, cfg)
             if axes is None:
                 # placement needs the logical-axis tree; derived by
                 # abstract tracing (no weight materialization) when the
@@ -330,7 +329,7 @@ class ServingEngine:
         if sc.kv_dtype is not None and not self.paged:
             raise ValueError(
                 "ServeConfig.kv_dtype requires a paged attention policy "
-                "(backend 'paged'/'paged_interpret') — only the page pool "
+                "(backend 'paged') — only the page pool "
                 "stores quantized K/V (docs/quant.md#kv-pages)")
         self.scheduler = sc.scheduler if sc.scheduler is not None \
             else Scheduler()
@@ -383,7 +382,7 @@ class ServingEngine:
         if sc.prefix_cache and not self.paged:
             raise ValueError(
                 "ServeConfig.prefix_cache requires a paged attention "
-                "policy (backend 'paged'/'paged_interpret') — prefix "
+                "policy (backend 'paged') — prefix "
                 "sharing aliases pool pages through block tables, which "
                 "the contiguous per-slot KV slab has none of")
         if self.paged:

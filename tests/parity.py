@@ -64,7 +64,7 @@ from repro.core import quant as Q
 from repro.core.plan import AttentionPolicy, GemmPolicy
 from repro.kernels.ref import mha_ref
 
-BACKENDS = ("xla", "blockflow", "pallas_interpret")
+BACKENDS = ("xla", "blockflow", "pallas")
 DTYPES = ("float32", "bfloat16", "int8")
 
 # (M, K, N): MXU-aligned, multi-block, ragged/odd (padding paths), and the
@@ -167,7 +167,7 @@ def check_quantized_cell(backend: str,
 # Attention grid (backend × dtype × case)
 # ---------------------------------------------------------------------------
 
-ATTN_BACKENDS = ("unfused", "fused_interpret", "paged_interpret")
+ATTN_BACKENDS = ("unfused", "fused", "paged")
 ATTN_DTYPES = ("float32", "bfloat16")       # fp only: scores are fp32 always
 ATTN_PAGE_SIZE = 16                          # key-block of the paged cells
 
@@ -383,7 +383,7 @@ def run_attention_grid(backends: Sequence[str] = ATTN_BACKENDS,
 MESH_SHAPES = ((1, 1), (2, 1), (1, 2), (2, 2))
 
 SHARDED_GEMM_BACKENDS = ("xla", "blockflow")
-SHARDED_ATTN_BACKENDS = ("fused_interpret", "paged_interpret")
+SHARDED_ATTN_BACKENDS = ("fused", "paged")
 
 
 def make_tp_mesh(shape: Tuple[int, int]):
@@ -557,7 +557,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                          "dtypes in --dtypes)")
     ap.add_argument("--attn-backends", nargs="+",
                     default=list(ATTN_BACKENDS),
-                    help="attention grid backends; paged_interpret cells "
+                    help="attention grid backends; paged cells "
                          "read K/V through shuffled block tables over a "
                          "distractor-laden page pool")
     ap.add_argument("--sharded", action="store_true",

@@ -61,7 +61,7 @@ def gemm_contract(**over):
 def paged_contract(**over):
     kw = dict(B=2, Sq=1, H=4, Hkv=2, D=16, Dv=16, P=8, page_size=16,
               block_tables=np.array([[2, 0, 5], [1, 3, 4]], np.int32),
-              block_q=32)
+              block_q=32, interpret=True)
     kw.update(over)
     return get_contract_builder("paged_attention")(**kw)
 
@@ -85,6 +85,19 @@ def test_paged_contract_clean_including_quantized():
     assert check_contract(paged_contract(quantized=True)) == []
 
 
+def test_paged_contract_lane_tiling_precondition():
+    """Compiled (not interpreted), the folded-lane blocks need head dims in
+    multiples of 128 unless there is one head: D=16 is a precondition
+    violation, D=128 and a single head are clean."""
+    v = check_contract(paged_contract(interpret=False))
+    assert kinds(v) == {"precondition"}
+    assert "TPU lane tiling" in v[0].detail
+    assert check_contract(paged_contract(interpret=False, D=128,
+                                         Dv=128)) == []
+    assert check_contract(paged_contract(interpret=False, H=1,
+                                         Hkv=1)) == []
+
+
 def test_full_sweep_zero_violations():
     """The CI gate, in-process: every backend × dtype × shape plus the
     configs/ registry must contract-check clean."""
@@ -93,7 +106,7 @@ def test_full_sweep_zero_violations():
 
 
 def test_plan_validate_accepts_auto_mode_choices():
-    for backend in ("blockflow", "pallas_interpret"):
+    for backend in ("blockflow", "pallas"):
         for (M, K, N) in parity.SHAPES:
             plan(M, N, K, "float32", GemmPolicy(backend=backend),
                  validate=True)       # raises ContractViolationError if bad
@@ -264,7 +277,7 @@ def test_lint_flags_weak_type_input():
 
 
 def test_lint_flags_fp64_promotion():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: x * np.float64(2.0))(jnp.ones(3, jnp.float32))
     findings = lint_jaxpr(jaxpr, check_weak_invars=False)
@@ -293,13 +306,10 @@ def test_lint_recurses_into_jitted_subjaxprs():
 
     findings = lint_jaxpr(jax.make_jaxpr(lambda x: inner(x) + 1)(jnp.ones(3)))
     assert any(f.rule == "host-callback" for f in findings)
-    assert any("pjit" in f.path for f in findings)
+    assert any("/jit:" in f.path for f in findings)
 
 
-def test_serving_engine_hot_path_lints_clean():
-    """The jitted prefill/decode closures — the per-request programs — must
-    carry no host syncs, fp64 upcasts, weak-type retrace triggers, or
-    scale-less int8 pools."""
+def lint_smoke_engine(**serve_kw):
     from repro.analysis.trace_lint import lint_engine
     from repro.configs.registry import get_smoke_config
     from repro.models import transformer as T
@@ -307,8 +317,26 @@ def test_serving_engine_hot_path_lints_clean():
 
     cfg = get_smoke_config("smollm-135m", n_layers=2, vocab=64)
     params, _ = T.init_model(jax.random.PRNGKey(0), cfg)
-    eng = ServingEngine(cfg, params, ServeConfig(batch_slots=2, max_len=32))
-    assert lint_engine(eng) == []
+    sc = ServeConfig(batch_slots=2, max_len=32, **serve_kw)
+    return lint_engine(ServingEngine(cfg, params, sc))
+
+
+def test_serving_engine_hot_path_lints_clean():
+    """The jitted prefill/decode closures — the per-request programs — must
+    carry no host syncs, fp64 upcasts, weak-type retrace triggers, or
+    scale-less int8 pools."""
+    assert lint_smoke_engine() == []
+
+
+def test_serving_engine_int8_pages_lint_clean():
+    """The same on int8 pages: the paged kernel consumes int8 pools, so the
+    scale rule sees the pools it guards and must find their scales."""
+    from repro.core.plan import AttentionPolicy
+
+    assert lint_smoke_engine(
+        kv_dtype="int8",
+        attention=AttentionPolicy(backend="paged",
+                                  page_size=8)) == []
 
 
 def test_sweep_grid_matches_parity():

@@ -9,7 +9,7 @@ from repro.core import api
 from repro.core.plan import GemmPolicy
 from repro.kernels.ref import matmul_ref
 
-BACKENDS = ["xla", "blockflow", "pallas_interpret"]
+BACKENDS = ["xla", "blockflow", "pallas"]
 
 
 def test_default_policy_resolves_xla_on_cpu():
@@ -22,8 +22,8 @@ def test_policy_context_restores():
     with api.use_policy(GemmPolicy(backend="blockflow")):
         assert api.resolved_backend() == "blockflow"
         assert not api.prefers_einsum()
-        with api.use_policy(GemmPolicy(backend="pallas_interpret")):
-            assert api.resolved_backend() == "pallas_interpret"
+        with api.use_policy(GemmPolicy(backend="pallas")):
+            assert api.resolved_backend() == "pallas"
         assert api.resolved_backend() == "blockflow"
     assert api.resolved_backend() == "xla"
 

@@ -16,7 +16,7 @@ from repro.models import transformer as T
 from repro.serving.engine import ServeConfig, ServingEngine
 from repro.serving.frontend import AsyncServingEngine
 
-PAGED8 = AttentionPolicy(backend="paged_interpret", page_size=8, block_q=8)
+PAGED8 = AttentionPolicy(backend="paged", page_size=8, block_q=8)
 
 
 @pytest.fixture(scope="module")

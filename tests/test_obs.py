@@ -25,7 +25,7 @@ from repro.obs import (
 )
 from repro.serving.engine import ServeConfig, ServingEngine
 
-PAGED8 = AttentionPolicy(backend="paged_interpret", page_size=8, block_q=8)
+PAGED8 = AttentionPolicy(backend="paged", page_size=8, block_q=8)
 
 
 @pytest.fixture(scope="module")

@@ -164,6 +164,24 @@ def test_int8_pages_validation():
         paged_attention(q, qk, vp, bt, kv_scales=(ks, vs), interpret=True)
 
 
+def test_compiled_kernel_refuses_lane_misaligned_heads():
+    """Compiled for a TPU, heads fold into D-wide lane blocks, so a head dim
+    that is not a multiple of 128 (smollm-135m's 64) is refused with a
+    ValueError naming the cause before Mosaic sees it; interpret mode and a
+    single head are exempt."""
+    rng = np.random.default_rng(19)
+    B, T, Hkv, D, ps = 1, 16, 2, 64, 8
+    k, v, kp, vp, bt = build_paged(rng, B, T, Hkv, D, ps)
+    q = jnp.asarray(rng.standard_normal((B, 2, Hkv, D)).astype(np.float32))
+    with pytest.raises(ValueError, match="multiples of 128"):
+        paged_attention(q, kp, vp, bt, interpret=False)
+    ref = paged_attention(q, kp, vp, bt, interpret=True)
+    assert ref.shape == (B, 2, Hkv, D)
+    one = paged_attention(q[:, :, :1], kp[:, :, :1], vp[:, :, :1], bt,
+                          interpret=True)
+    assert one.shape == (B, 2, 1, D)
+
+
 def test_soft_cap_and_bf16():
     rng = np.random.default_rng(11)
     B, T, H, D, ps = 1, 32, 2, 16, 16

@@ -42,7 +42,7 @@ def test_attention_quantized_kv_parity(case):
     int8 pages + per-page-per-head scales, dequantized inside the kernel's
     K/V fetch, vs mha_ref on the dequantized pool (docs/quant.md#kv-pages).
     Same case set as the fp grid — offsets, GQA, masked rows included."""
-    parity.check_quantized_attention_cell("paged_interpret", case)
+    parity.check_quantized_attention_cell("paged", case)
 
 
 def test_attention_fused_vs_unfused_direct():
@@ -68,10 +68,9 @@ def test_attention_fused_vs_unfused_direct():
         outs[b] = np.asarray(api.attention(
             *operands, q_positions=qp, kv_valid_len=kl, causal=case.causal,
             policy=pol, **kw))
-    np.testing.assert_allclose(outs["unfused"], outs["fused_interpret"],
+    np.testing.assert_allclose(outs["unfused"], outs["fused"],
                                atol=3e-5, rtol=3e-5)
-    np.testing.assert_array_equal(outs["paged_interpret"],
-                                  outs["fused_interpret"])
+    np.testing.assert_array_equal(outs["paged"], outs["fused"])
 
 
 def test_dense_backends_reject_block_tables():
@@ -84,7 +83,7 @@ def test_dense_backends_reject_block_tables():
     q = jnp.zeros((1, 1, 2, 8))
     kp = jnp.zeros((4, 16, 1, 8))
     bt = jnp.zeros((1, 2), jnp.int32)
-    for b in ("unfused", "fused_interpret"):
+    for b in ("unfused", "fused"):
         with pytest.raises(ValueError, match="paged"):
             api.attention(q, kp, kp, q_positions=jnp.zeros((1, 1), jnp.int32),
                           kv_valid_len=jnp.ones((1,), jnp.int32),

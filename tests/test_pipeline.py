@@ -14,10 +14,11 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro.distributed.pipeline import pipeline_apply, stack_for_stages
+    from repro.launch.mesh import make_mesh
 
     S, LperS, mu, mb, d = 4, 2, 6, 3, 16
     L = S * LperS
-    mesh = jax.make_mesh((S, 2), ("stage", "model"))
+    mesh = make_mesh((S, 2), ("stage", "model"))
     key = jax.random.PRNGKey(0)
     Ws = jax.random.normal(key, (L, d, d), jnp.float32) * (1.0 / d ** 0.5)
     x = jax.random.normal(jax.random.fold_in(key, 1), (mu, mb, d))

@@ -36,9 +36,9 @@ def test_plan_cache_hits_on_repeated_shapes():
 
 def test_plan_resolves_layout_and_acc():
     pln = P.plan(64, 384, 256, jnp.bfloat16,
-                 GemmPolicy(backend="pallas_interpret", mode="dm"))
+                 GemmPolicy(backend="pallas", mode="dm"))
     assert isinstance(pln, ExecutionPlan)
-    assert pln.backend == "pallas_interpret"
+    assert pln.backend == "pallas"
     assert pln.mode == "dm"
     assert pln.layout.mode == "dm"
     assert pln.acc == jnp.dtype(jnp.float32)
@@ -117,7 +117,7 @@ def test_register_backend_no_silent_overwrite():
 
 def test_builtin_backends_present():
     names = P.registered_backends()
-    for expected in ("xla", "pallas", "pallas_interpret", "blockflow"):
+    for expected in ("xla", "pallas", "blockflow"):
         assert expected in names
 
 
@@ -137,10 +137,10 @@ def test_pack_weight_roundtrip():
 
 def test_packed_linear_bitwise_identical_pallas_interpret():
     """Acceptance: linear with a PackedWeight is bitwise-identical to the
-    row-major path under pallas_interpret — same kernel, same blocks, minus
-    the per-call re-layout."""
+    row-major path under pallas (interpreted off-TPU) — same kernel, same
+    blocks, minus the per-call re-layout."""
     rng = np.random.default_rng(1)
-    pol = GemmPolicy(backend="pallas_interpret", mode="dm")
+    pol = GemmPolicy(backend="pallas", mode="dm")
     x = jnp.asarray(rng.standard_normal((64, 256)).astype(np.float32))
     w = jnp.asarray(rng.standard_normal((256, 384)).astype(np.float32))
     y_row = api.linear(x, w, policy=pol)
@@ -216,12 +216,12 @@ def test_layout_for_packed_respects_calling_budget():
     named error instead of silently overflowing VMEM."""
     w = jnp.ones((2048, 512), jnp.float32)
     pw = P.pack_weight(w, GemmPolicy(mode="dm"))     # bk=2048, bn=512
-    mid = GemmPolicy(backend="pallas_interpret", mode="dc",
+    mid = GemmPolicy(backend="pallas", mode="dc",
                      vmem_budget=12 * 1024 * 1024)
     blk = P.layout_for_packed(512, pw, jnp.float32, mid)
     assert (blk.bk, blk.bn) == (pw.bk, pw.bn)
     assert blk.vmem_bytes(4) <= mid.vmem_budget
-    tight = GemmPolicy(backend="pallas_interpret", mode="dc",
+    tight = GemmPolicy(backend="pallas", mode="dc",
                        vmem_budget=2 * 1024 * 1024)
     with pytest.raises(ValueError, match="cannot fit"):
         P.layout_for_packed(512, pw, jnp.float32, tight)
@@ -257,7 +257,7 @@ def test_policy_is_hashable_and_frozen():
 
 def test_attention_policy_hashable_and_frozen():
     from repro.core.plan import AttentionPolicy
-    pol = AttentionPolicy(backend="fused_interpret", block_q=64)
+    pol = AttentionPolicy(backend="fused", block_q=64)
     assert hash(pol) == hash(dataclasses.replace(pol))
     with pytest.raises(dataclasses.FrozenInstanceError):
         pol.backend = "unfused"
@@ -270,11 +270,11 @@ def test_attention_auto_resolution_mirrors_gemm():
     expect = "fused" if jax.default_backend() == "tpu" else "unfused"
     assert resolve_attention_backend("auto") == expect
     assert AttentionPolicy().resolved_backend() == expect
-    assert resolve_attention_backend("fused_interpret") == "fused_interpret"
+    assert resolve_attention_backend("fused") == "fused"
 
 
 def test_attention_registry_builtins_and_errors():
-    assert {"fused", "fused_interpret", "unfused"} <= set(
+    assert {"fused", "paged", "unfused"} <= set(
         P.registered_attention_backends())
     with pytest.raises(ValueError, match="already registered"):
         P.register_attention_backend("unfused", lambda *a, **k: None)
@@ -312,7 +312,7 @@ def test_attention_registry_custom_backend_dispatch():
 def test_use_attention_policy_nests_thread_local():
     from repro.core.plan import AttentionPolicy
     base = api.current_attention_policy()
-    inner = AttentionPolicy(backend="fused_interpret", block_q=32)
+    inner = AttentionPolicy(backend="fused", block_q=32)
     with api.use_attention_policy(inner):
         assert api.current_attention_policy() is inner
         with api.use_attention_policy(AttentionPolicy(backend="unfused")):

@@ -18,7 +18,7 @@ from repro.serving.engine import ServeConfig, ServingEngine, _Waiting
 from repro.serving.kv_pool import BlockTable
 from repro.serving.scheduler import RequestView, Scheduler, SLOScheduler
 
-PAGED8 = AttentionPolicy(backend="paged_interpret", page_size=8, block_q=8)
+PAGED8 = AttentionPolicy(backend="paged", page_size=8, block_q=8)
 
 
 @pytest.fixture(scope="module")

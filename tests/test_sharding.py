@@ -39,6 +39,23 @@ def test_divisibility_fallback():
     assert spec == P(None, None)               # 9 % 16 != 0 → replicate
 
 
+@pytest.mark.parametrize("model,kv_spec", [(4, "model"), (16, None)])
+def test_head_units_keep_heads_whole(model, kv_spec):
+    """qwen3-8b's fused KV projection is 1024 wide, which 16 divides, but
+    its 8 KV heads do not: at TP=16 it replicates rather than split a head;
+    at TP=4 it shards, two heads a shard. Query heads (32) shard at both."""
+    cfg = get_config("qwen3-8b")
+
+    class Fake(shd.ShardingRules):
+        def _mesh_size(self, axes):
+            return model if axes == "model" else 1
+    rules = Fake(one_device_mesh(), cfg.overrides_dict(), cfg.head_units())
+    kv_width = cfg.n_kv_heads * cfg.head_dim
+    assert rules.spec(("embed", "kv_heads"), (cfg.d_model, kv_width)) == P(
+        "data", kv_spec)
+    assert rules.spec(("embed", "heads")) == P("data", "model")
+
+
 def test_missing_mesh_axis_dropped():
     """'pod' doesn't exist on the single-pod mesh → silently dropped."""
     mesh = one_device_mesh(("data", "model"))

@@ -14,8 +14,8 @@ from repro.serving.engine import ServeConfig, ServingEngine
 from repro.serving.spec_decode import (DraftModelDrafter, NGramDrafter,
                                        make_drafter)
 
-PAGED8 = AttentionPolicy(backend="paged_interpret", page_size=8, block_q=8)
-FUSED8 = AttentionPolicy(backend="fused_interpret", block_q=8, block_k=8)
+PAGED8 = AttentionPolicy(backend="paged", page_size=8, block_q=8)
+FUSED8 = AttentionPolicy(backend="fused", block_q=8, block_k=8)
 
 
 @pytest.fixture(scope="module")

@@ -42,7 +42,7 @@ from repro.launch.mesh import make_host_mesh                  # noqa: E402
 from repro.models import transformer as T                     # noqa: E402
 from repro.serving.engine import ServeConfig, ServingEngine   # noqa: E402
 
-PAGED = AttentionPolicy(backend="paged_interpret", page_size=8, block_q=8)
+PAGED = AttentionPolicy(backend="paged", page_size=8, block_q=8)
 
 
 def build():
