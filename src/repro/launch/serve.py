@@ -129,7 +129,7 @@ def main(argv=None):
     scheduler = (Scheduler(prefill_chunk=args.prefill_chunk)
                  if args.prefill_chunk else None)
     # one recorder across the continuous-batching and async engines: their
-    # phase spans land on shared tracks, request ids on async tracks
+    # engine spans land on shared tracks, request ids on async tracks
     obs = (Observability() if (args.trace_out or args.metrics_json)
            else NULL_OBS)
     print(f"[serve] arch={cfg.name} slots={args.batch_slots} "

@@ -13,9 +13,11 @@ registry and a :class:`~repro.obs.tracing.TraceRecorder`:
     print(json.dumps(obs.metrics.snapshot()))
 
 The default everywhere is :data:`NULL_OBS` — ``enabled=False``, null
-metrics, null recorder. Every per-token call site in the engine is
-guarded by ``if obs.enabled:`` so the disabled path costs one attribute
-load + branch and allocates nothing (tests/test_obs.py pins this).
+metrics, null recorder. Engine spans (``obs.trace.span(name, **args)``)
+still reach a profiler capture as ``jax.profiler.TraceAnnotation``s, and
+record nothing else; every per-token call site in the engine is guarded
+by ``if obs.enabled:`` so the disabled path costs one attribute load +
+branch there and allocates nothing (tests/test_obs.py pins this).
 """
 from __future__ import annotations
 
@@ -30,7 +32,6 @@ from repro.obs.metrics import (
     json_scalars,
     merge_histograms,
     quantile,
-    timed,
     validate_metrics_snapshot,
 )
 from repro.obs.tracing import (
@@ -40,6 +41,7 @@ from repro.obs.tracing import (
     RequestTrace,
     TraceRecorder,
     aggregate_request_traces,
+    install_gc_span,
     validate_trace,
 )
 
@@ -47,11 +49,12 @@ __all__ = [
     "Observability", "NULL_OBS",
     # metrics
     "Metrics", "NULL_METRICS", "Counter", "Gauge", "Histogram",
-    "Timer", "timed", "quantile", "json_scalars", "merge_histograms",
+    "Timer", "quantile", "json_scalars", "merge_histograms",
     "validate_metrics_snapshot", "TIME_BUCKETS_S",
     # tracing
     "TraceRecorder", "NullRecorder", "NULL_RECORDER", "PHASE_TRACKS",
     "RequestTrace", "aggregate_request_traces", "validate_trace",
+    "install_gc_span",
 ]
 
 

@@ -29,8 +29,6 @@ pairs in launch/serve.py, launch/train.py and train/loop.py::
     with Timer() as tm:
         out = engine.generate(prompts, n)
     print(f"done in {tm.dt:.2f}s")
-
-Optionally it feeds a histogram on exit (``timed(hist)``).
 """
 from __future__ import annotations
 
@@ -41,7 +39,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Metrics", "NULL_METRICS", "Timer",
-    "timed", "quantile", "TIME_BUCKETS_S", "json_scalars",
+    "quantile", "TIME_BUCKETS_S", "json_scalars",
     "validate_metrics_snapshot", "merge_histograms",
 ]
 
@@ -283,15 +281,13 @@ class Timer:
 
     ``tm.dt`` is the elapsed seconds — final once the block exits, running
     while still inside it (so progress prints mid-block work too).
-    ``tm.ms`` is the same in milliseconds. With ``histogram`` the duration
-    is observed on exit (the ``timed(hist)`` spelling)."""
+    ``tm.ms`` is the same in milliseconds."""
 
-    __slots__ = ("_t0", "_dt", "_hist")
+    __slots__ = ("_t0", "_dt")
 
-    def __init__(self, histogram: Optional[Histogram] = None):
+    def __init__(self):
         self._t0 = 0.0
         self._dt: Optional[float] = None
-        self._hist = histogram
 
     def __enter__(self) -> "Timer":
         self._t0 = time.perf_counter()
@@ -299,8 +295,6 @@ class Timer:
 
     def __exit__(self, *exc) -> None:
         self._dt = time.perf_counter() - self._t0
-        if self._hist is not None:
-            self._hist.observe(self._dt)
 
     @property
     def dt(self) -> float:
@@ -310,11 +304,6 @@ class Timer:
     @property
     def ms(self) -> float:
         return self.dt * 1e3
-
-
-def timed(histogram: Optional[Histogram]) -> Timer:
-    """``with timed(hist): ...`` — a Timer that records into ``hist``."""
-    return Timer(histogram)
 
 
 def quantile(values: Sequence[float], q: float) -> float:

@@ -1,22 +1,35 @@
-"""Span/event recorder with a ring buffer and a Perfetto trace exporter.
+"""Engine spans on the profiler's clock, a span/event ring buffer, and a
+Perfetto trace exporter.
 
-The recorder collects host-side timing events from the serving engine —
-**never** from inside a jitted closure (repro.analysis.trace_lint proves
-the traced prefill/decode programs stay callback-free) — into a bounded
-ring buffer (``collections.deque(maxlen=...)``: a long-running server
-keeps the most recent window, oldest events drop first, ``dropped``
-counts them).
+Every engine span goes through one entry point, ``recorder.span(name,
+**args)`` (a context manager). It always enters a
+``jax.profiler.TraceAnnotation(name, **args)``, so a profiler capture
+(``jax.profiler.start_trace``, the benchmark's ``--trace 1`` run) holds the
+engine's phases on the host plane of the same ``.xplane.pb`` as the device
+ops; with no profiler session active that costs about a microsecond. A
+live :class:`TraceRecorder` also records the span into its ring, for
+``--trace-out``. Spans are host-side only, **never** inside a jitted
+closure (repro.analysis.trace_lint proves the traced prefill/decode
+programs stay callback-free). :func:`install_gc_span` adds a ``gc`` span
+around each of Python's garbage collections, so a host stall in a trace is
+either covered by one or not.
 
-Two families of events (docs/observability.md#span-taxonomy):
+The ring is a ``collections.deque(maxlen=...)``: a long-running server
+keeps the most recent window, oldest events drop first, ``dropped`` counts
+them. Two families of events (docs/observability.md#span-taxonomy):
 
-* **Phase tracks** — one named track per engine phase (``admit``,
-  ``prefill-chunk``, ``decode-step``, ``preempt``, ``resume``,
-  ``evict``): complete spans (Chrome ``ph: "X"``) recorded by the engine
-  around each phase's host+device work.
+* **Span tracks** — one named track per span name (``PHASE_TRACKS``:
+  ``engine.step`` and its children ``engine.schedule``,
+  ``engine.prefill``, ``engine.decode``, ``engine.wait``,
+  ``engine.emit``; ``engine.admit``, ``engine.cancel``,
+  ``engine.preempt``, ``engine.resume``, ``engine.evict``,
+  ``engine.draft``, ``engine.verify``): complete spans (Chrome
+  ``ph: "X"``).
 * **Request tracks** — one async track per request id (Chrome
   ``ph: "b"/"n"/"e"`` with ``cat: "request"``), spanning submit →
   first-token → retire with instants for prefix hits, preemptions and
-  resumes. Perfetto groups them by id under the engine process.
+  resumes. Perfetto groups them by id under the engine process. They
+  live in the ring only.
 
 :meth:`TraceRecorder.export` renders the ring into the Chrome trace
 JSON object format (``{"traceEvents": [...]}``) that Perfetto
@@ -29,37 +42,86 @@ balanced (``validate_trace`` checks it, along with X-span nesting).
 :class:`RequestTrace` is the per-request lifecycle record the engine
 builds alongside the trace events and serves via
 ``ServingEngine.request_trace(handle)``: queue wait, prefill chunks,
-TTFT, per-token inter-arrival histogram + raw timestamps, preemption
-count, prefix-cache hit span, and pages held over time.
-:func:`aggregate_request_traces` folds many of them into the SLO
-percentile summary (p50/p95/p99 TTFT and ITL).
+TTFT, raw per-token timestamps, preemption count, prefix-cache hit span,
+and pages held over time. :func:`aggregate_request_traces` folds many of
+them into the SLO percentile summary (p50/p95/p99 TTFT and ITL).
 
-Everything is stdlib-only; the disabled path is :data:`NULL_RECORDER`,
-whose methods are no-ops and which never allocates per call.
+The disabled path is :data:`NULL_RECORDER`: its ``span`` is the bare
+profiler annotation, and nothing else it does records or allocates.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import gc
 import json
 import time
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
-from repro.obs.metrics import Histogram, TIME_BUCKETS_S, quantile
+from jax.profiler import TraceAnnotation
+
+from repro.obs.metrics import quantile
 
 __all__ = [
     "TraceRecorder", "NullRecorder", "NULL_RECORDER", "PHASE_TRACKS",
     "RequestTrace", "aggregate_request_traces", "validate_trace",
+    "install_gc_span",
 ]
 
-# The engine's phase tracks, in display order (exporter assigns tids and
+# The engine's span names, in display order (exporter assigns tids and
 # thread_sort_index in this order; unknown tracks append after).
 PHASE_TRACKS: Tuple[str, ...] = (
-    "admit", "prefill-chunk", "decode-step", "preempt", "resume", "evict",
-    "draft", "verify",   # speculative decoding (serving/spec_decode.py)
+    "engine.step", "engine.schedule", "engine.prefill", "engine.decode",
+    "engine.wait", "engine.emit", "engine.admit", "engine.cancel",
+    "engine.preempt", "engine.resume", "engine.evict",
+    "engine.draft", "engine.verify",   # speculative decoding
 )
 
 _ENGINE_PID = 1
+
+
+class _RecordedSpan:
+    """What :meth:`TraceRecorder.span` returns: the profiler annotation,
+    plus one complete span in the recorder's ring on exit."""
+
+    __slots__ = ("_rec", "_name", "_args", "_ann", "_t0")
+
+    def __init__(self, rec: "TraceRecorder", name: str, args: Dict):
+        self._rec, self._name, self._args = rec, name, args
+        self._ann = TraceAnnotation(name, **args)
+        self._t0 = 0.0
+
+    def __enter__(self) -> "_RecordedSpan":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._rec.complete(self._name, self._name, self._t0,
+                           args=self._args or None)
+        self._ann.__exit__(*exc)
+
+
+# the garbage collection in progress, if any: one at a time per process
+_gc_open: List[TraceAnnotation] = []
+
+
+def _gc_span(phase: str, info: Dict) -> None:
+    if phase == "start":
+        ann = TraceAnnotation("gc", generation=info["generation"])
+        ann.__enter__()
+        _gc_open.append(ann)
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+def install_gc_span() -> None:
+    """Put a ``gc`` span (``jax.profiler.TraceAnnotation``, with the
+    collected ``generation``) around each of Python's garbage collections
+    from now on, once per process. Costs about a microsecond per
+    collection while no profiler session is active."""
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
 
 
 class TraceRecorder:
@@ -81,6 +143,11 @@ class TraceRecorder:
         self.dropped = 0
 
     # -- recording ----------------------------------------------------------
+    def span(self, name: str, **args) -> _RecordedSpan:
+        """Context manager: ``name`` as a profiler annotation with
+        ``args``, and as a complete span on the track ``name``."""
+        return _RecordedSpan(self, name, args)
+
     def _push(self, ev: Tuple) -> None:
         if len(self._events) == self.capacity:
             self.dropped += 1
@@ -92,7 +159,7 @@ class TraceRecorder:
     def complete(self, track: str, name: str, t0: float,
                  t1: Optional[float] = None,
                  args: Optional[Dict] = None) -> None:
-        """One complete span on a phase track: began at perf_counter time
+        """One complete span on ``track``: began at perf_counter time
         ``t0``, ended at ``t1`` (now when omitted)."""
         if t1 is None:
             t1 = time.perf_counter()
@@ -212,6 +279,11 @@ class NullRecorder:
     dropped = 0
     capacity = 0
 
+    @staticmethod
+    def span(name: str, **args) -> TraceAnnotation:
+        """The profiler annotation alone (see :meth:`TraceRecorder.span`)."""
+        return TraceAnnotation(name, **args)
+
     def complete(self, track, name, t0, t1=None, args=None) -> None:
         pass
 
@@ -268,12 +340,10 @@ class RequestTrace:
     #                                      cache at first admission
     tokens: List[int] = dataclasses.field(default_factory=list)
     token_s: List[float] = dataclasses.field(default_factory=list)
-    prefill_chunks: List[Dict[str, float]] = dataclasses.field(
-        default_factory=list)            # {start_pos, tokens, dt_s}
+    prefill_chunks: List[Dict[str, int]] = dataclasses.field(
+        default_factory=list)            # {start_pos, tokens}
     pages_timeline: List[Tuple[int, int]] = dataclasses.field(
         default_factory=list)            # (engine tick, pages held)
-    itl: Histogram = dataclasses.field(
-        default_factory=lambda: Histogram("request_itl_s", TIME_BUCKETS_S))
     deadline_missed: Optional[bool] = None
     # transient: set while parked in the wait queue (preempt → resume)
     preempted_at_s: Optional[float] = None
@@ -289,8 +359,7 @@ class RequestTrace:
         return self.first_token_s - self.submit_s
 
     def itl_list(self) -> List[float]:
-        """Raw inter-token gaps (exact; ``itl`` holds the same data
-        bucketed for cheap merging)."""
+        """Exact inter-token gaps, from ``token_s``."""
         return [b - a for a, b in zip(self.token_s, self.token_s[1:])]
 
     def to_json(self) -> Dict[str, object]:
@@ -314,7 +383,6 @@ class RequestTrace:
             "prefill_chunks": list(self.prefill_chunks),
             "pages_timeline": [[int(t), int(p)]
                                for t, p in self.pages_timeline],
-            "itl": self.itl.snapshot(),
             "deadline_missed": self.deadline_missed,
         }
 

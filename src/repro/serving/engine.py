@@ -61,7 +61,7 @@ from repro.core.plan import AttentionPolicy, GemmPolicy, ShardingPolicy
 from repro.distributed import tp as TP
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
-from repro.obs import NULL_OBS, Observability, RequestTrace
+from repro.obs import NULL_OBS, Observability, RequestTrace, install_gc_span
 from repro.obs.metrics import TIME_BUCKETS_S, json_scalars
 from repro.serving.kv_pool import BlockTable, PagePool
 from repro.serving.prefix_cache import PrefixCache
@@ -336,9 +336,11 @@ class ServingEngine:
         # Observability: instruments are registered once here (the slow
         # phase) and held as direct references — the per-token paths only
         # ever do `if obs.enabled:` plus an int add. With NULL_OBS every
-        # instrument is the shared no-op and nothing is recorded.
+        # instrument is the shared no-op and nothing is recorded; the
+        # engine's spans (obs.trace.span) still reach a profiler capture.
         self.obs = sc.obs if sc.obs is not None else NULL_OBS
         obs = self.obs
+        install_gc_span()
         if obs.enabled:
             m = obs.metrics
             self._m_prefill_tokens = m.counter("engine_tokens_total",
@@ -354,10 +356,6 @@ class ServingEngine:
             self._m_cancelled = m.counter("engine_cancelled_total")
             self._m_live = m.gauge("engine_live_requests")
             self._m_waiting = m.gauge("engine_waiting_requests")
-            self._h_prefill = m.histogram("engine_prefill_chunk_s",
-                                          TIME_BUCKETS_S)
-            self._h_decode = m.histogram("engine_decode_step_s",
-                                         TIME_BUCKETS_S)
             self._h_ttft = m.histogram("request_ttft_s", TIME_BUCKETS_S)
             self._h_itl = m.histogram("request_itl_s", TIME_BUCKETS_S)
             if self.spec is not None:
@@ -445,12 +443,20 @@ class ServingEngine:
         self.slot_priority = np.zeros(B, np.int64)
         self.slot_deadline: List[Optional[float]] = [None] * B
         self.slot_arrival = np.zeros(B, np.int64)
-        # Observability (stats()): a monotonic host tick orders arrivals;
-        # token counters split prefill from decode work.
+        # Observability (stats()): a monotonic host tick (one per submit()
+        # and step() call) orders arrivals; token counters split prefill
+        # from decode work, and row counters count what the model computed
+        # for it (B * Sb per prefill call, B per decode call, padding
+        # included). engine_s is host time inside submit()/step()/cancel(),
+        # engine_wait_s the part of it blocked on device results.
         self.tick = 0
         self.n_preemptions = 0
         self.prefill_tokens = 0
         self.decode_tokens = 0
+        self.prefill_rows = 0
+        self.decode_rows = 0
+        self.engine_s = 0.0
+        self.engine_wait_s = 0.0
         # Speculative-decoding counters (stats()): drafted tokens the
         # target's greedy choice confirmed vs rejected, and pool pages
         # returned by rejection rollback (BlockTable.truncate).
@@ -469,6 +475,40 @@ class ServingEngine:
                 key, logits.astype(jnp.float32) / self.sc.temperature,
                 axis=-1)
         return jnp.argmax(logits, axis=-1)
+
+    @contextlib.contextmanager
+    def _timed(self, name: str, *, wait: bool = False, **args):
+        """Span ``name`` (with ``args``) round the block, and its host
+        seconds added to ``engine_s`` — or, with ``wait``, to
+        ``engine_wait_s``: submit(), step() and cancel() run inside the
+        former, every block on the device inside the latter."""
+        t0 = time.perf_counter()
+        try:
+            with self.obs.trace.span(name, **args):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            if wait:
+                self.engine_wait_s += dt
+            else:
+                self.engine_s += dt
+
+    def _wait(self, x: jax.Array, what: str) -> np.ndarray:
+        """Bring device result ``x`` to the host inside an ``engine.wait``
+        span (``what`` names the result)."""
+        with self._timed("engine.wait", wait=True, what=what):
+            return np.asarray(x)
+
+    def _wait_caches(self):
+        """Wait, inside an ``engine.wait`` span, for any call still writing
+        the caches (a prefill chunk submit() dispatched), before a dispatch
+        that makes a fresh copy of them. The caches are not donated, so a
+        call in flight holds its input and output copies; where the device
+        cannot hold one more, that dispatch would block in the runtime's
+        allocator until the call ends, a wait ``engine_wait_s`` would not
+        see. The dispatch then goes out when it would have anyway."""
+        with self._timed("engine.wait", wait=True, what="caches"):
+            jax.block_until_ready(self.caches)
 
     def _reset_slot_caches(self, slot: int):
         """Zero a slot's valid lengths so a recycled slot starts from
@@ -539,6 +579,8 @@ class ServingEngine:
         TP (heads are), so the same indexed copy works mesh-resident —
         every shard duplicates its own head slice, keeping per-shard pools
         in lockstep."""
+        self._wait_caches()
+
         def rec(node):
             if isinstance(node, dict):
                 out = {}
@@ -805,54 +847,45 @@ class ServingEngine:
             raise ValueError(
                 f"prompt length {len(prompt)} out of range for "
                 f"max_len={self.sc.max_len} (need 1 <= len < max_len)")
-        prompt = [int(t) for t in prompt]
-        self.tick += 1
-        arrival = self.tick
-        obs = self.obs
-        t0 = time.perf_counter() if obs.enabled else 0.0
-        if not self.paged:
-            free = np.where(~self.slot_live)[0]
-            if free.size == 0:
-                return None
-            slot = int(free[0])
-            self.slot_priority[slot] = priority
-            self.slot_deadline[slot] = deadline
-            self.slot_arrival[slot] = arrival
-            self.slot_prompt[slot] = prompt
-            self._begin_admit(slot, prompt, key=key)
-            if obs.enabled:
-                obs.trace.complete("admit", f"admit {slot}", t0,
-                                   args={"handle": slot,
-                                         "prompt_len": len(prompt)})
-            return slot
-        incoming = RequestView(rid=self._next_rid, priority=priority,
-                               deadline=deadline, arrival=arrival,
-                               n_tokens=len(prompt))
-        while True:
-            free = np.where(~self.slot_live)[0]
-            if free.size and self._paged_admit(
-                    int(free[0]), self._next_rid, prompt, prompt,
-                    key=key, priority=priority, deadline=deadline,
-                    arrival=arrival):
-                rid = self._next_rid
-                self._next_rid += 1
-                if obs.enabled:
-                    obs.trace.complete("admit", f"admit rid={rid}", t0,
-                                       args={"rid": rid,
-                                             "prompt_len": len(prompt)})
-                return rid
-            # no slot, or not enough pages even after cold-cache eviction:
-            # ask the policy whether this request may displace a live one
-            live = [s for s in range(self.sc.batch_slots)
-                    if self.slot_live[s]]
-            if not live:
-                return None
-            vrid = self.scheduler.victim([self._view(s) for s in live])
-            vslot = self._slot_of_rid(vrid)
-            if not self.scheduler.should_preempt(incoming,
-                                                 self._view(vslot)):
-                return None          # page/slot-bound, not worth churning
-            self._preempt(vslot)
+        with self._timed("engine.admit", prompt_len=len(prompt)):
+            prompt = [int(t) for t in prompt]
+            self.tick += 1
+            arrival = self.tick
+            if not self.paged:
+                free = np.where(~self.slot_live)[0]
+                if free.size == 0:
+                    return None
+                slot = int(free[0])
+                self.slot_priority[slot] = priority
+                self.slot_deadline[slot] = deadline
+                self.slot_arrival[slot] = arrival
+                self.slot_prompt[slot] = prompt
+                self._begin_admit(slot, prompt, key=key)
+                return slot
+            incoming = RequestView(rid=self._next_rid, priority=priority,
+                                   deadline=deadline, arrival=arrival,
+                                   n_tokens=len(prompt))
+            while True:
+                free = np.where(~self.slot_live)[0]
+                if free.size and self._paged_admit(
+                        int(free[0]), self._next_rid, prompt, prompt,
+                        key=key, priority=priority, deadline=deadline,
+                        arrival=arrival):
+                    rid = self._next_rid
+                    self._next_rid += 1
+                    return rid
+                # no slot, or not enough pages even after cold-cache eviction:
+                # ask the policy whether this request may displace a live one
+                live = [s for s in range(self.sc.batch_slots)
+                        if self.slot_live[s]]
+                if not live:
+                    return None
+                vrid = self.scheduler.victim([self._view(s) for s in live])
+                vslot = self._slot_of_rid(vrid)
+                if not self.scheduler.should_preempt(incoming,
+                                                     self._view(vslot)):
+                    return None          # page/slot-bound, not worth churning
+                self._preempt(vslot)
 
     def _begin_admit(self, slot: int, tokens: List[int], *,
                      start: int = 0,
@@ -916,7 +949,6 @@ class ServingEngine:
         L = len(tokens)
         p0 = int(self.slot_pos[slot])
         obs = self.obs
-        t0 = time.perf_counter() if obs.enabled else 0.0
         budget = self.scheduler.prefill_chunk or (L - p0)
         n = min(budget, L - p0)
         B = self.sc.batch_slots
@@ -928,33 +960,30 @@ class ServingEngine:
             Sb = n
         else:
             Sb = min(_next_pow2(n), max(self.sc.max_len, n))
-        tok = np.zeros((B, Sb), np.int32)
-        tok[slot, :n] = tokens[p0:p0 + n]
-        pos = np.full((B, Sb), -1, np.int32)
-        pos[slot, :n] = np.arange(p0, p0 + n)
-        batch = {"tokens": self._dev(tok), "positions": self._dev(pos),
-                 "last_cols": self._dev(jnp.full((B,), n - 1, jnp.int32))}
-        if self.paged:
-            batch["block_tables"] = self._bt_device()
-        logits, self.caches = self.prefill(self.params, batch, self.caches)
+        h = self._handle(slot)
+        # host assembly + dispatch
+        with obs.trace.span("engine.prefill", rid=h, start=p0, tokens=n,
+                            rows=B * Sb):
+            tok = np.zeros((B, Sb), np.int32)
+            tok[slot, :n] = tokens[p0:p0 + n]
+            pos = np.full((B, Sb), -1, np.int32)
+            pos[slot, :n] = np.arange(p0, p0 + n)
+            batch = {"tokens": self._dev(tok), "positions": self._dev(pos),
+                     "last_cols": self._dev(jnp.full((B,), n - 1,
+                                                     jnp.int32))}
+            if self.paged:
+                batch["block_tables"] = self._bt_device()
+            self._wait_caches()
+            logits, self.caches = self.prefill(self.params, batch,
+                                               self.caches)
         self.prefill_tokens += n
+        self.prefill_rows += B * Sb
         self.slot_pos[slot] = p0 + n
         if obs.enabled:
-            # timing covers host assembly + dispatch (the device call is
-            # async; nothing here forces a sync the uninstrumented engine
-            # wouldn't do)
-            t1 = time.perf_counter()
-            h = self._handle(slot)
             self._m_prefill_tokens.inc(n)
-            self._h_prefill.observe(t1 - t0)
-            obs.trace.complete("prefill-chunk",
-                               f"prefill rid={h} [{p0}:{p0 + n})", t0, t1,
-                               args={"rid": h, "start": p0, "tokens": n})
             rt = self.request_traces.get(h)
             if rt is not None:
-                rt.prefill_chunks.append(
-                    {"start_pos": p0, "tokens": n,
-                     "dt_s": round(t1 - t0, 6)})
+                rt.prefill_chunks.append({"start_pos": p0, "tokens": n})
         if p0 + n < L:
             return False               # more chunks on later steps
         self.slot_prefilling[slot] = False
@@ -965,8 +994,9 @@ class ServingEngine:
         else:
             # fresh admission — or a resume preempted before its first
             # sample existed: the stored key re-seeds it identically
-            self.slot_next[slot] = int(self._sample(
-                logits[slot][None], self.slot_pf_key[slot])[0])
+            self.slot_next[slot] = int(self._wait(self._sample(
+                logits[slot][None], self.slot_pf_key[slot]),
+                "first_token")[0])
         if self.prefix is not None:
             # index the ORIGINAL prompt's full pages (never the generated
             # tail: decode writes positions >= len(prompt), so these pages
@@ -988,14 +1018,10 @@ class ServingEngine:
         if self.pool.can_alloc(n):
             return True
         if self.prefix is not None:
-            obs = self.obs
-            t0 = time.perf_counter() if obs.enabled else 0.0
             short = n - self.pool.free_pages
-            freed = self.prefix.evict(short)
-            if obs.enabled:
-                obs.trace.complete("evict", f"evict {freed}p on-demand",
-                                   t0, args={"requested": short,
-                                             "freed": freed})
+            with self.obs.trace.span("engine.evict", requested=short,
+                                     why="on-demand"):
+                self.prefix.evict(short)
         return self.pool.can_alloc(n)
 
     def _paged_admit(self, slot: int, rid: int, prompt: List[int],
@@ -1057,43 +1083,41 @@ class ServingEngine:
         when enabled, so a preempted request's shared prefix re-admits
         without re-prefilling (docs/serving.md)."""
         obs = self.obs
-        if obs.enabled:
-            t0 = time.perf_counter()
-            h = self._handle(slot)   # before slot_rid resets below
-        if self.slot_prefilling[slot]:
-            # mid-chunked-prefill: no pending token was sampled yet; park
-            # the sampling key (and any carried token from an earlier
-            # preemption) so resume reproduces the stream exactly
-            restore = self.slot_pf_restore[slot]
-            next_tok = None if restore is None else restore.next_tok
-            key = self.slot_pf_key[slot]
-        else:
-            next_tok = int(self.slot_next[slot])
-            key = None
-        self.wait.append(_Waiting(
-            rid=int(self.slot_rid[slot]), prompt=self.slot_prompt[slot],
-            out=self.slot_out[slot], next_tok=next_tok, key=key,
-            priority=int(self.slot_priority[slot]),
-            deadline=self.slot_deadline[slot],
-            arrival=int(self.slot_arrival[slot])))
-        self.n_preemptions += 1
-        self.slot_tables[slot].free()
-        self.slot_tables[slot] = None
-        self.block_tables[slot] = 0
-        self.slot_rid[slot] = -1
-        self.slot_live[slot] = False
-        self.slot_drain[slot] = False
-        self.slot_prefilling[slot] = False
-        self.slot_pf_tokens[slot] = None
-        self.slot_pf_restore[slot] = None
-        self.slot_pf_key[slot] = None
-        # slot_pos stays nonzero → the next admission resets this slot's lens
+        h = self._handle(slot)       # before slot_rid resets below
+        with obs.trace.span("engine.preempt", rid=h):
+            if self.slot_prefilling[slot]:
+                # mid-chunked-prefill: no pending token was sampled yet; park
+                # the sampling key (and any carried token from an earlier
+                # preemption) so resume reproduces the stream exactly
+                restore = self.slot_pf_restore[slot]
+                next_tok = None if restore is None else restore.next_tok
+                key = self.slot_pf_key[slot]
+            else:
+                next_tok = int(self.slot_next[slot])
+                key = None
+            self.wait.append(_Waiting(
+                rid=int(self.slot_rid[slot]), prompt=self.slot_prompt[slot],
+                out=self.slot_out[slot], next_tok=next_tok, key=key,
+                priority=int(self.slot_priority[slot]),
+                deadline=self.slot_deadline[slot],
+                arrival=int(self.slot_arrival[slot])))
+            self.n_preemptions += 1
+            self.slot_tables[slot].free()
+            self.slot_tables[slot] = None
+            self.block_tables[slot] = 0
+            self.slot_rid[slot] = -1
+            self.slot_live[slot] = False
+            self.slot_drain[slot] = False
+            self.slot_prefilling[slot] = False
+            self.slot_pf_tokens[slot] = None
+            self.slot_pf_restore[slot] = None
+            self.slot_pf_key[slot] = None
+            # slot_pos stays nonzero → the next admission resets the
+            # slot's lens
         if obs.enabled:
             self._m_preemptions.inc()
             self._m_live.set(int(self.slot_live.sum()))
             self._m_waiting.set(len(self.wait))
-            obs.trace.complete("preempt", f"preempt rid={h}", t0,
-                               args={"rid": h})
             obs.trace.async_instant(h, "preempt")
             rt = self.request_traces.get(h)
             if rt is not None:
@@ -1119,15 +1143,13 @@ class ServingEngine:
             if free.size == 0:
                 break
             w = self.wait[i]
-            t0 = time.perf_counter() if obs.enabled else 0.0
-            if self._paged_admit(int(free[0]), w.rid, w.prompt,
-                                 w.prompt + w.out, restore=w, key=w.key,
-                                 priority=w.priority, deadline=w.deadline,
-                                 arrival=w.arrival):
-                admitted.append(i)
-                if obs.enabled:
-                    obs.trace.complete("resume", f"resume rid={w.rid}", t0,
-                                       args={"rid": w.rid})
+            with obs.trace.span("engine.resume", rid=w.rid):
+                if self._paged_admit(int(free[0]), w.rid, w.prompt,
+                                     w.prompt + w.out, restore=w, key=w.key,
+                                     priority=w.priority,
+                                     deadline=w.deadline,
+                                     arrival=w.arrival):
+                    admitted.append(i)
         for i in sorted(admitted, reverse=True):
             self.wait.pop(i)
         if obs.enabled and admitted:
@@ -1227,29 +1249,31 @@ class ServingEngine:
         """Abort a request by the handle submit() returned (request id in
         paged mode, slot id else), releasing its slot — and, when paged,
         its pages (or its wait-queue entry). Returns True if found."""
-        if not self.paged:
-            if 0 <= handle < self.sc.batch_slots and self.slot_live[handle]:
-                self._retire(handle, cancelled=True)
-                return True
+        with self._timed("engine.cancel", handle=int(handle)):
+            if not self.paged:
+                if (0 <= handle < self.sc.batch_slots
+                        and self.slot_live[handle]):
+                    self._retire(handle, cancelled=True)
+                    return True
+                return False
+            for s in range(self.sc.batch_slots):
+                if self.slot_live[s] and self.slot_rid[s] == handle:
+                    self._retire(s, cancelled=True)
+                    self.request_out.pop(handle, None)
+                    return True
+            for i, w in enumerate(self.wait):
+                if w.rid == handle:
+                    self.wait.pop(i)
+                    self.request_out.pop(handle, None)
+                    if self.obs.enabled:
+                        self._m_cancelled.inc()
+                        self._m_waiting.set(len(self.wait))
+                        self.obs.trace.async_end(handle, {"cancelled": True})
+                        rt = self.request_traces.get(handle)
+                        if rt is not None and rt.retire_s is None:
+                            rt.retire_s = time.perf_counter()
+                    return True
             return False
-        for s in range(self.sc.batch_slots):
-            if self.slot_live[s] and self.slot_rid[s] == handle:
-                self._retire(s, cancelled=True)
-                self.request_out.pop(handle, None)
-                return True
-        for i, w in enumerate(self.wait):
-            if w.rid == handle:
-                self.wait.pop(i)
-                self.request_out.pop(handle, None)
-                if self.obs.enabled:
-                    self._m_cancelled.inc()
-                    self._m_waiting.set(len(self.wait))
-                    self.obs.trace.async_end(handle, {"cancelled": True})
-                    rt = self.request_traces.get(handle)
-                    if rt is not None and rt.retire_s is None:
-                        rt.retire_s = time.perf_counter()
-                return True
-        return False
 
     def step(self, key: Optional[jax.Array] = None) -> Dict[int, int]:
         """One decode iteration across all live slots; non-live, draining
@@ -1281,58 +1305,73 @@ class ServingEngine:
         a burst of accepted tokens per request — instead of one token
         each; concatenated bursts equal the non-speculative stream
         exactly (docs/serving.md#speculative-decoding).
+
+        Spans: ``engine.step`` holds ``engine.schedule`` (before and after
+        the prefill chunk), ``engine.prefill``, ``engine.decode``,
+        ``engine.wait`` and ``engine.emit`` (docs/observability.md).
         """
         self.tick += 1
-        obs = self.obs
-        if self.paged:
-            if self.prefix is not None and self.sc.prefix_watermark > 0:
-                short = self.sc.prefix_watermark - self.pool.free_pages
-                if short > 0:
-                    t0 = time.perf_counter() if obs.enabled else 0.0
-                    freed = self.prefix.evict(short)
-                    if obs.enabled:
-                        obs.trace.complete(
-                            "evict", f"evict {freed}p watermark", t0,
-                            args={"requested": short, "freed": freed})
-            self._try_resume()
-        if not self.slot_live.any():
-            return {}
-        # one chunked-prefill advance per step: bounded prefill work keeps
-        # decode latency jitter bounded (the whole point of chunking);
-        # unchunked admissions never appear here — submit() finishes them
-        pf = [s for s in range(self.sc.batch_slots)
-              if self.slot_prefilling[s]]
-        if pf:
-            s = min(pf, key=lambda t: (self.slot_priority[t],
-                                       self.slot_arrival[t], t))
-            self._prefill_slot_chunk(s)
-        if self.spec is not None:
-            return self._spec_step()
-        if self.paged:
-            self._grow_pages_for_decode()
-        decodable = (self.slot_live & ~self.slot_drain
-                     & ~self.slot_prefilling)
-        nxt = None
-        if decodable.any():
-            t0 = time.perf_counter() if obs.enabled else 0.0
-            tok = self._dev(np.asarray(self.slot_next)[:, None])
-            pos = self._dev(np.where(decodable, self.slot_pos,
-                                     -1).astype(np.int32)[:, None])
-            bt = self._bt_device() if self.paged else None
-            logits, self.caches = self.decode(self.params, tok, pos,
-                                              self.caches, bt)
-            nxt = np.asarray(self._sample(logits, key))
-            n_dec = int(decodable.sum())
-            self.decode_tokens += n_dec
-            if obs.enabled:
-                # np.asarray above synced the sampled ids, so this span is
-                # honest wall time for the whole batched decode
-                t1 = time.perf_counter()
-                self._m_decode_tokens.inc(n_dec)
-                self._h_decode.observe(t1 - t0)
-                obs.trace.complete("decode-step", f"decode x{n_dec}",
-                                   t0, t1,
-                                   args={"slots": n_dec, "tick": self.tick})
+        with self._timed("engine.step", tick=self.tick):
+            obs = self.obs
+            with obs.trace.span("engine.schedule"):
+                if self.paged:
+                    self._evict_to_watermark()
+                    self._try_resume()
+                # one chunked-prefill advance per step: bounded prefill
+                # work keeps decode latency jitter bounded (the whole point
+                # of chunking); unchunked admissions never appear here —
+                # submit() finishes them
+                pf = [s for s in range(self.sc.batch_slots)
+                      if self.slot_prefilling[s]]
+                chunk = min(pf, key=lambda t: (self.slot_priority[t],
+                                               self.slot_arrival[t], t),
+                            default=None)
+            if not self.slot_live.any():
+                return {}
+            if chunk is not None:
+                self._prefill_slot_chunk(chunk)
+            if self.spec is not None:
+                return self._spec_step()
+            if self.paged:
+                with obs.trace.span("engine.schedule"):
+                    self._grow_pages_for_decode()
+            decodable = (self.slot_live & ~self.slot_drain
+                         & ~self.slot_prefilling)
+            nxt = None
+            if decodable.any():
+                n_dec = int(decodable.sum())
+                with obs.trace.span("engine.decode", slots=n_dec):
+                    tok = self._dev(np.asarray(self.slot_next)[:, None])
+                    pos = self._dev(np.where(decodable, self.slot_pos,
+                                             -1).astype(np.int32)[:, None])
+                    bt = self._bt_device() if self.paged else None
+                    logits, self.caches = self.decode(self.params, tok, pos,
+                                                      self.caches, bt)
+                    ids = self._sample(logits, key)
+                nxt = self._wait(ids, "decode_ids")
+                self.decode_tokens += n_dec
+                self.decode_rows += self.sc.batch_slots
+                if obs.enabled:
+                    self._m_decode_tokens.inc(n_dec)
+            with obs.trace.span("engine.emit"):
+                return self._emit(nxt)
+
+    def _evict_to_watermark(self):
+        """Evict cold prefix-cache entries until
+        ``ServeConfig.prefix_watermark`` pages are free (step()'s first
+        scheduling act)."""
+        if self.prefix is None or self.sc.prefix_watermark <= 0:
+            return
+        short = self.sc.prefix_watermark - self.pool.free_pages
+        if short > 0:
+            with self.obs.trace.span("engine.evict", requested=short,
+                                     why="watermark"):
+                self.prefix.evict(short)
+
+    def _emit(self, nxt: Optional[np.ndarray]) -> Dict[int, int]:
+        """Report each live slot's pending token, retire drained slots and
+        advance the rest to the ids ``nxt`` just decoded (the tail of
+        :meth:`step`)."""
         out = {}
         for s in range(self.sc.batch_slots):
             if not self.slot_live[s] or self.slot_prefilling[s]:
@@ -1341,7 +1380,7 @@ class ServingEngine:
             self.slot_out[s].append(t)
             h = self._handle(s)
             out[h] = t
-            if obs.enabled:
+            if self.obs.enabled:
                 self._obs_token(s, h, t)
             if self.slot_drain[s]:      # final pending token flushed above
                 self._retire(s)
@@ -1382,59 +1421,61 @@ class ServingEngine:
                      & ~self.slot_prefilling)
         drafts: Dict[int, List[int]] = {}
         if decodable.any():
-            t0 = time.perf_counter() if obs.enabled else 0.0
-            for s in np.nonzero(decodable)[0]:
-                s = int(s)
-                pos = int(self.slot_pos[s])
-                # verify writes positions pos..pos+m; the last writable
-                # cache index is max_len - 1, so m <= max_len - 1 - pos
-                cap = min(k, self.sc.max_len - 1 - pos)
-                d: List[int] = []
-                if cap >= 1:
-                    ctx = (self.slot_prompt[s] + self.slot_out[s]
-                           + [int(self.slot_next[s])])
-                    d = [int(t) for t in self.spec.draft(ctx, cap)][:cap]
-                drafts[s] = d
-            if obs.enabled:
-                obs.trace.complete(
-                    "draft", f"draft x{len(drafts)}", t0,
-                    args={"slots": len(drafts),
-                          "tokens": sum(len(d) for d in drafts.values()),
-                          "tick": self.tick})
+            w0 = self.spec.wait_s
+            with obs.trace.span("engine.draft",
+                                slots=int(decodable.sum())):
+                for s in np.nonzero(decodable)[0]:
+                    s = int(s)
+                    pos = int(self.slot_pos[s])
+                    # verify writes positions pos..pos+m; the last writable
+                    # cache index is max_len - 1, so m <= max_len - 1 - pos
+                    cap = min(k, self.sc.max_len - 1 - pos)
+                    d: List[int] = []
+                    if cap >= 1:
+                        ctx = (self.slot_prompt[s] + self.slot_out[s]
+                               + [int(self.slot_next[s])])
+                        d = [int(t) for t in self.spec.draft(ctx, cap)][:cap]
+                    drafts[s] = d
+            # a drafter with a model blocks on its own device results
+            self.engine_wait_s += self.spec.wait_s - w0
         if self.paged:
             # may preempt for the base pos+1 page and TRIM drafts in
             # place when speculation alone would exhaust the pool
-            self._grow_pages_for_decode(drafts)
+            with obs.trace.span("engine.schedule"):
+                self._grow_pages_for_decode(drafts)
             decodable = (self.slot_live & ~self.slot_drain
                          & ~self.slot_prefilling)
         nxt = None
         if decodable.any():
-            t0 = time.perf_counter() if obs.enabled else 0.0
             B = self.sc.batch_slots
-            tok = np.zeros((B, 1 + k), np.int32)
-            pos2 = np.full((B, 1 + k), -1, np.int32)
-            for s in np.nonzero(decodable)[0]:
-                s = int(s)
-                d = drafts.get(s, [])
-                m = len(d)
-                p = int(self.slot_pos[s])
-                tok[s, 0] = int(self.slot_next[s])
-                tok[s, 1:1 + m] = d
-                pos2[s, :1 + m] = np.arange(p, p + 1 + m)
-            batch = {"tokens": self._dev(tok),
-                     "positions": self._dev(pos2)}
-            if self.paged:
-                batch["block_tables"] = self._bt_device()
-            greedy, self.caches = self.verify(self.params, batch,
-                                              self.caches)
-            nxt = np.asarray(greedy)
-            if obs.enabled:
-                t1 = time.perf_counter()
-                self._h_decode.observe(t1 - t0)
-                obs.trace.complete(
-                    "verify", f"verify x{int(decodable.sum())}", t0, t1,
-                    args={"slots": int(decodable.sum()),
-                          "tick": self.tick})
+            with obs.trace.span("engine.verify",
+                                slots=int(decodable.sum())):
+                tok = np.zeros((B, 1 + k), np.int32)
+                pos2 = np.full((B, 1 + k), -1, np.int32)
+                for s in np.nonzero(decodable)[0]:
+                    s = int(s)
+                    d = drafts.get(s, [])
+                    m = len(d)
+                    p = int(self.slot_pos[s])
+                    tok[s, 0] = int(self.slot_next[s])
+                    tok[s, 1:1 + m] = d
+                    pos2[s, :1 + m] = np.arange(p, p + 1 + m)
+                batch = {"tokens": self._dev(tok),
+                         "positions": self._dev(pos2)}
+                if self.paged:
+                    batch["block_tables"] = self._bt_device()
+                greedy, self.caches = self.verify(self.params, batch,
+                                                  self.caches)
+            nxt = self._wait(greedy, "verify_ids")
+            self.decode_rows += B * (1 + k)
+        with obs.trace.span("engine.emit"):
+            return self._spec_emit(drafts, nxt)
+
+    def _spec_emit(self, drafts: Dict[int, List[int]],
+                   nxt: Optional[np.ndarray]) -> Dict[int, List[int]]:
+        """Report each slot's accepted burst, retire drained slots and roll
+        rejected drafts back (the tail of :meth:`_spec_step`)."""
+        obs = self.obs
         out: Dict[int, List[int]] = {}
         len_resets: Dict[int, int] = {}
         for s in range(self.sc.batch_slots):
@@ -1505,9 +1546,7 @@ class ServingEngine:
                 self._h_ttft.observe(now - rt.submit_s)
                 self.obs.trace.async_instant(h, "first-token")
             else:
-                gap = now - rt.token_s[-1]
-                rt.itl.observe(gap)
-                self._h_itl.observe(gap)
+                self._h_itl.observe(now - rt.token_s[-1])
             rt.tokens.append(tok)
             rt.token_s.append(now)
             if self.paged:
@@ -1521,8 +1560,8 @@ class ServingEngine:
                       ) -> Optional[RequestTrace]:
         """The lifecycle record for ``handle`` (repro.obs.RequestTrace):
         queue/preemption waits, prefill chunks, TTFT, the exact reported
-        token stream with per-token timestamps, the inter-token-latency
-        histogram, and the pages-held timeline. None when observability is
+        token stream with per-token timestamps, and the pages-held
+        timeline. None when observability is
         disabled or the handle is unknown. Records persist past
         retirement; ``pop=True`` removes the record after returning it (a
         long-running server's analogue of ``request_out.pop``)."""
@@ -1532,8 +1571,11 @@ class ServingEngine:
 
     def stats(self) -> Dict[str, object]:
         """One flat observability snapshot: scheduling churn, prefill vs
-        decode token split, pool pressure, and (when enabled) the prefix
-        cache's hit/miss/eviction counters. Printed by launch/serve.py and
+        decode token split and the rows the model computed for them, host
+        seconds inside submit()/step()/cancel() and the part of them spent
+        waiting on the device, pool pressure, and (when enabled) the prefix
+        cache's hit/miss/eviction counters. All are always on, whatever
+        ``ServeConfig.obs`` is. Printed by launch/serve.py and
         recorded per-row in benchmarks/serving_sweep.py JSONL — every
         value is coerced to a plain JSON type (json_scalars), so the dict
         round-trips through json.dumps unchanged (tests/test_obs.py pins
@@ -1545,6 +1587,10 @@ class ServingEngine:
             "n_preemptions": self.n_preemptions,
             "prefill_tokens": self.prefill_tokens,
             "decode_tokens": self.decode_tokens,
+            "prefill_rows": self.prefill_rows,
+            "decode_rows": self.decode_rows,
+            "engine_s": self.engine_s,
+            "engine_wait_s": self.engine_wait_s,
         }
         if self.spec is not None:
             seen = self.spec_accepted + self.spec_rejected

@@ -59,9 +59,14 @@ class Drafter:
     ``k`` on the instance is the engine's per-step draft budget; the
     per-call ``k`` argument may be smaller when the engine trimmed the
     budget to its page pool or ``max_len`` horizon.
+
+    ``wait_s`` is the host seconds the drafter has spent blocked on the
+    device so far; the engine adds what a step's drafting adds to it to
+    its own ``engine_wait_s``.
     """
 
     k: int = 4
+    wait_s: float = 0.0
 
     def draft(self, context: List[int], k: int) -> List[int]:
         raise NotImplementedError
@@ -147,6 +152,10 @@ class DraftModelDrafter(Drafter):
         self._eng = ServingEngine(cfg, params, ServeConfig(
             batch_slots=1, max_len=int(max_len) + self.k + 2,
             attention=attention))
+
+    @property
+    def wait_s(self) -> float:
+        return self._eng.engine_wait_s
 
     def draft(self, context: List[int], k: int) -> List[int]:
         k = min(k, self.k)

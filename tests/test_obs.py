@@ -1,5 +1,7 @@
 """Observability (repro/obs): metrics registry, Perfetto tracing,
-per-request lifecycle records, null-mode zero-cost, engine integration."""
+engine spans on the profiler's clock, per-request lifecycle records,
+null-mode zero-cost, engine integration."""
+import gc
 import json
 
 import jax
@@ -18,12 +20,14 @@ from repro.obs import (
     Timer,
     TraceRecorder,
     aggregate_request_traces,
+    install_gc_span,
     merge_histograms,
     quantile,
     validate_metrics_snapshot,
     validate_trace,
 )
 from repro.serving.engine import ServeConfig, ServingEngine
+from repro.serving.scheduler import Scheduler
 
 PAGED8 = AttentionPolicy(backend="paged", page_size=8, block_q=8)
 
@@ -114,10 +118,6 @@ def test_timer():
         sum(range(1000))
     assert tm.dt > 0.0
     assert tm.ms == pytest.approx(tm.dt * 1e3)
-    h = Histogram("t_s")
-    with Timer(h):
-        pass
-    assert h.count == 1
 
 
 # -- tracing ----------------------------------------------------------------
@@ -125,9 +125,9 @@ def test_timer():
 def test_trace_export_valid_and_balanced():
     tr = TraceRecorder()
     t0 = tr.epoch
-    tr.complete("decode-step", "decode x2", t0, t0 + 0.001,
+    tr.complete("engine.decode", "engine.decode", t0, t0 + 0.001,
                 args={"slots": 2})
-    tr.instant("evict", "evict 3p")
+    tr.instant("engine.evict", "evict 3p")
     tr.async_begin(7, {"prompt_len": 4})
     tr.async_instant(7, "first-token")
     tr.async_end(7, {"n_tokens": 5})
@@ -177,11 +177,18 @@ def test_validate_trace_catches_imbalance():
 def test_null_obs_records_nothing(setup):
     cfg, params = setup
     eng = ServingEngine(cfg, params, ServeConfig(
-        batch_slots=2, max_len=32, attention=PAGED8))   # default NULL_OBS
+        batch_slots=2, max_len=32, attention=PAGED8,
+        scheduler=Scheduler(prefill_chunk=2)))   # default NULL_OBS
     assert eng.obs is NULL_OBS
-    eng.submit([1, 2, 3])
+    h = eng.submit([1, 2, 3])
     for _ in range(4):
         eng.step()
+    assert eng.cancel(h) is True
+    # every span site ran (the always-on counters moved) ...
+    st = eng.stats()
+    assert st["prefill_rows"] > 0 and st["decode_rows"] > 0
+    assert st["engine_wait_s"] > 0
+    # ... and none of them recorded anything outside the profiler
     assert len(eng.obs.trace) == 0
     snap = eng.obs.metrics.snapshot()
     assert all(v == {} for v in snap.values())   # no series registered
@@ -219,8 +226,8 @@ def test_request_trace_token_exact_across_preempt_resume(setup):
         assert rt.ttft_s() is not None and rt.ttft_s() > 0
         assert rt.retire_s is not None
         assert rt.prompt_len == 3
-        assert rt.itl.count == len(rt.tokens) - 1
         assert len(rt.itl_list()) == len(rt.tokens) - 1
+        assert all(g >= 0 for g in rt.itl_list())
         assert rt.pages_timeline                       # pages were tracked
         preempted += rt.n_preemptions
         assert json.loads(json.dumps(rt.to_json())) == rt.to_json()
@@ -236,7 +243,7 @@ def test_request_trace_token_exact_across_preempt_resume(setup):
     assert validate_trace(doc) == []
     tracks = {e.get("args", {}).get("name")
               for e in doc["traceEvents"] if e["ph"] == "M"}
-    assert "preempt" in tracks and "resume" in tracks
+    assert "engine.preempt" in tracks and "engine.resume" in tracks
     assert validate_metrics_snapshot(sc.obs.metrics.snapshot()) == []
     snap = sc.obs.metrics.snapshot()
     assert snap["counters"]["engine_preemptions_total"] == eng.n_preemptions
@@ -262,8 +269,15 @@ def test_engine_metrics_match_stats(setup):
     assert c["prefix_hit_tokens_total"] == st["prefix_hit_tokens"]
     assert snap["gauges"]["pool_pages_in_use"] == st["pool_pages_in_use"]
     assert snap["gauges"]["pool_high_water_pages"] == st["pool_high_water"]
-    assert snap["histograms"]["engine_prefill_chunk_s"]["count"] >= 1
-    assert snap["histograms"]["engine_decode_step_s"]["count"] >= 1
+    prefills = [e for e in obs.trace.export()["traceEvents"]
+                if e["ph"] == "X" and e["name"] == "engine.prefill"]
+    assert len(prefills) >= 1
+    assert sum(e["args"]["tokens"] for e in prefills) == st["prefill_tokens"]
+    assert sum(e["args"]["rows"] for e in prefills) == st["prefill_rows"]
+    decodes = [e for e in obs.trace.export()["traceEvents"]
+               if e["ph"] == "X" and e["name"] == "engine.decode"]
+    assert len(decodes) >= 1
+    assert sum(e["args"]["slots"] for e in decodes) == st["decode_tokens"]
     # prefix hit recorded on the request's own trace too
     rts = sorted(eng.request_traces.values(), key=lambda t: t.rid)
     assert rts[1].prefix_hit_tokens > 0
@@ -274,7 +288,8 @@ def test_stats_json_roundtrip_both_backends(setup):
     json.dumps round-trips and the key schema is pinned."""
     cfg, params = setup
     core = {"tick", "live_requests", "waiting_requests", "n_preemptions",
-            "prefill_tokens", "decode_tokens"}
+            "prefill_tokens", "decode_tokens", "prefill_rows", "decode_rows",
+            "engine_s", "engine_wait_s"}
     paged_keys = core | {
         "pool_pages", "pool_free_pages", "pool_pages_in_use",
         "pool_high_water", "kv_dtype", "kv_page_bytes", "kv_pool_bytes",
@@ -410,7 +425,161 @@ def test_spec_metrics_and_phase_spans(setup):
     assert validate_trace(doc) == []
     tracks = {e["args"]["name"] for e in doc["traceEvents"]
               if e["ph"] == "M" and e["name"] == "thread_name"}
-    assert {"draft", "verify"} <= tracks
+    assert {"engine.draft", "engine.verify"} <= tracks
     spans = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
-    assert any(n.startswith("draft x") for n in spans)
-    assert any(n.startswith("verify x") for n in spans)
+    assert {"engine.draft", "engine.verify", "engine.wait"} <= spans
+
+
+# -- spans on the profiler's clock, always-on counters ----------------------
+
+def profiled_host_events(tmp_path, fn):
+    """Run ``fn()`` under a ``jax.profiler`` session; return the events of
+    the ``/host:CPU`` plane of the ``.xplane.pb`` it wrote, as
+    ``(name, start_ns, end_ns, stats)``."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0          # annotations only
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(str(path)).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                            {k: v for k, v in e.stats}))
+    return out
+
+
+def test_engine_spans_on_profiler_host_plane(setup, tmp_path):
+    """With the default NULL_OBS, a few steps of a paged engine under a
+    profiler session put engine.step and its children on the host plane,
+    with their args, each child inside an engine.step."""
+    cfg, params = setup
+    B = 2
+    eng = ServingEngine(cfg, params, ServeConfig(
+        batch_slots=B, max_len=32, attention=PAGED8,
+        scheduler=Scheduler(prefill_chunk=4)))
+
+    rids = []
+
+    def serve():
+        rids.append(eng.submit([1, 2, 3, 4, 5, 6]))
+        for _ in range(3):
+            eng.step()
+        eng.cancel(rids[-1])
+
+    serve()                               # compile outside the session
+    events = profiled_host_events(tmp_path, serve)
+    by = {}
+    for ev in events:
+        by.setdefault(ev[0], []).append(ev)
+    for name in ("engine.step", "engine.schedule", "engine.prefill",
+                 "engine.decode", "engine.wait", "engine.emit",
+                 "engine.admit", "engine.cancel"):
+        assert name in by, (name, sorted(by))
+    assert len(by["engine.step"]) == 3
+    # the first chunk (4 tokens, Sb 4) runs in submit, the rest (2, Sb 2)
+    # in the first step
+    pf = sorted(by["engine.prefill"], key=lambda e: e[1])
+    assert [(e[3]["rid"], e[3]["start"], e[3]["tokens"], e[3]["rows"])
+            for e in pf] == [(rids[-1], 0, 4, B * 4), (rids[-1], 4, 2, B * 2)]
+    assert all(e[3]["slots"] == 1 for e in by["engine.decode"])
+    assert {e[3]["what"] for e in by["engine.wait"]} == \
+        {"caches", "first_token", "decode_ids"}
+    # the block on a call still writing the caches (the chunk submit()
+    # left running) is a wait inside each prefill's dispatch
+    caches = sorted((e for e in by["engine.wait"]
+                     if e[3]["what"] == "caches"), key=lambda e: e[1])
+    assert len(caches) == len(pf)
+    assert all(p[1] <= w[1] and w[2] <= p[2] for p, w in zip(pf, caches))
+    admit, = by["engine.admit"]
+    assert admit[1] <= pf[0][1] and pf[0][2] <= admit[2]
+    steps = by["engine.step"]
+
+    def inside_a_step(ev):
+        return any(s[1] <= ev[1] and ev[2] <= s[2] for s in steps)
+
+    for name in ("engine.schedule", "engine.decode", "engine.emit"):
+        assert all(inside_a_step(e) for e in by[name]), name
+    assert inside_a_step(pf[1])
+    waits = [e for e in by["engine.wait"] if e[3]["what"] == "decode_ids"]
+    assert len(waits) == 3 and all(inside_a_step(e) for e in waits)
+
+
+def test_row_counters_match_hand_arithmetic(setup):
+    """prefill_rows counts B * Sb per prefill call (Sb the power-of-two
+    bucket), decode_rows B per decode call, beside the real tokens."""
+    cfg, params = setup
+    B = 2
+    eng = ServingEngine(cfg, params, ServeConfig(
+        batch_slots=B, max_len=32, attention=PAGED8,
+        scheduler=Scheduler(prefill_chunk=4)))
+    eng.submit([1, 2, 3, 4, 5, 6])        # chunk of 4 tokens: Sb 4
+    st = eng.stats()
+    assert (st["prefill_tokens"], st["prefill_rows"]) == (4, B * 4)
+    assert (st["decode_tokens"], st["decode_rows"]) == (0, 0)
+    for _ in range(3):                    # chunk of 2 (Sb 2), 3 decodes
+        eng.step()
+    st = eng.stats()
+    assert (st["prefill_tokens"], st["prefill_rows"]) == (6, B * 4 + B * 2)
+    assert (st["decode_tokens"], st["decode_rows"]) == (3, 3 * B)
+    eng.submit(list(range(1, 6)))         # 5 tokens in one chunk of 4 + 1
+    st = eng.stats()
+    assert st["prefill_rows"] == B * 4 + B * 2 + B * 4
+
+
+def test_engine_wait_within_engine_s(setup):
+    """engine_wait_s is the part of engine_s spent blocked on the device;
+    both only grow."""
+    cfg, params = setup
+    eng = ServingEngine(cfg, params, ServeConfig(
+        batch_slots=2, max_len=32, attention=PAGED8))
+    st0 = eng.stats()
+    assert st0["engine_s"] == st0["engine_wait_s"] == 0.0
+    h = eng.submit([1, 2, 3])
+    st1 = eng.stats()
+    for _ in range(3):
+        eng.step()
+    eng.cancel(h)
+    st2 = eng.stats()
+    for st in (st1, st2):
+        assert 0.0 < st["engine_wait_s"] <= st["engine_s"]
+    assert st2["engine_s"] > st1["engine_s"]
+    assert st2["engine_wait_s"] > st1["engine_wait_s"]
+
+
+def test_draft_model_waits_count_as_engine_waits(setup):
+    """A model drafter's own engine blocks on the device inside the
+    target's engine.draft: those waits are the target's waits too."""
+    from repro.serving.spec_decode import DraftModelDrafter
+    cfg, params = setup
+    drafter = DraftModelDrafter(cfg, params, k=2, max_len=32,
+                                attention=PAGED8)
+    eng = ServingEngine(cfg, params, ServeConfig(
+        batch_slots=2, max_len=24, attention=PAGED8, spec=drafter))
+    eng.submit([1, 2, 3])
+    eng.submit([4, 5, 6, 7])
+    for _ in range(3):
+        eng.step()
+    st = eng.stats()
+    assert drafter.wait_s > 0
+    assert drafter.wait_s < st["engine_wait_s"] <= st["engine_s"]
+
+
+def test_gc_span_under_profiler(tmp_path):
+    """A forced collection under a profiler session leaves a ``gc`` span
+    on the host plane; installing twice hooks once."""
+    install_gc_span()
+    install_gc_span()
+    hooks = [cb for cb in gc.callbacks
+             if getattr(cb, "__module__", "") == "repro.obs.tracing"]
+    assert len(hooks) == 1
+    events = profiled_host_events(tmp_path, lambda: gc.collect())
+    gcs = [e for e in events if e[0] == "gc"]
+    assert gcs and any(e[3].get("generation") == 2 for e in gcs)
+    assert all(e[2] >= e[1] for e in gcs)
