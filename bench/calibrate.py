@@ -16,6 +16,11 @@ lower reading of a number is the largest a sound run gives; the upper the
 smallest the control gives (``PERF.md`` keeps both, and the limit set
 between them, in the configuration file's ``correct``).
 
+The program's tensor-parallel path refuses int8 weights
+(``ServeConfig.mesh`` with ``weight_dtype``), so for a cell on more than
+one chip ``--control-seeds`` stops the run before it touches a device;
+its sound runs and faults are read here as on one chip.
+
 The benchmark's own runs never run this.
 """
 import argparse
@@ -53,6 +58,12 @@ def main(argv=None) -> int:
 
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     cell = find(bench["workloads"], args.workload, "workload")
+    if cell["chips"] > 1 and args.control_seeds:
+        print(f"{args.workload} runs on {cell['chips']} chips, and the "
+              f"program's TP path refuses the int8 control (ServeConfig."
+              f"mesh with weight_dtype): no --control-seeds here",
+              file=sys.stderr)
+        return 2
     devices = jax.devices()
     if devices[0].platform != "tpu" or len(devices) < cell["chips"]:
         print(f"needs {cell['chips']} TPU chip(s)", file=sys.stderr)

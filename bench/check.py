@@ -3,12 +3,14 @@
 Once the window has closed, a sample of what it served, drawn from the
 seed, is run through the plain reference: the longest request the window
 finished, then others it finished or left running, until the sample holds
-some hundreds of served tokens. They go through the plain reference (``bench/reference/<model_type>.py``): each prompt with the
-tokens that were served for it, in one float32 forward. For every served
-token the reference gives the gap by which that token's logit lies below
-the reference's best logit at the same position (0 where the served token
-is the reference's own greedy choice). Two numbers are read, in logit
-units:
+some hundreds of served tokens. They go through the plain reference
+(``bench/reference/<model_type>.py``): each prompt with the tokens that
+were served for it, in one float32 forward, over weights made again from
+the seed in the placement the program had (sharded over the cell's chips
+where it has more than one). For every served token the reference gives
+the gap by which that token's logit lies below the reference's best logit
+at the same position (0 where the served token is the reference's own
+greedy choice). Two numbers are read, in logit units:
 
 - ``mean_gap``: the mean gap over the sample's served tokens;
 - ``max_gap``: the widest gap over the sample.
@@ -22,12 +24,14 @@ there; ``max_gap`` is printed beside it.
 """
 from __future__ import annotations
 
-import importlib
+from pathlib import Path
 from typing import Dict, List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench.model import load_module
 
 
 def sample(finished: Sequence, running: Sequence, seed: int,
@@ -54,8 +58,10 @@ class Reference:
     """The reference forward of one configuration, compiled once for
     sequences padded to ``max_len`` and answers padded to ``max_answer``."""
 
-    def __init__(self, raw: Dict, max_len: int, max_answer: int):
-        mod = importlib.import_module(f"bench.reference.{raw['model_type']}")
+    def __init__(self, root: Path, raw: Dict, max_len: int,
+                 max_answer: int):
+        mod = load_module(root / "bench" / "reference"
+                          / f"{raw['model_type']}.py")
         self.max_len, self.max_answer = max_len, max_answer
 
         @jax.jit

@@ -4,7 +4,11 @@ the reference, the result line.
 Everything that belongs to a configuration, a traffic mix or a per-layer
 metric is found by its name in ``BENCHMARK.json``:
 
-- ``bench/configs/<config>.json``: the model and its deployment;
+- ``bench/configs/<config>.json``: the model and its deployment, on the
+  chips its ``serving`` group names, which the cell's ``chips`` must equal;
+- ``bench/families/<model_type>.py``: what the configuration's model type
+  fixes for the program (``bench/model.py``), and any per-layer work that
+  ``bench/flops.py`` counts otherwise than for a dense decoder;
 - ``bench/traffic/<traffic>.json``: the mix, read by ``bench/workload.py``;
 - ``bench/metrics/<metric>.py``: a reader with ``read(run)`` that returns
   the metric's value, or None when the run has nothing to read;
@@ -15,7 +19,6 @@ So a cell or a metric is added with files and entries, not edits.
 from __future__ import annotations
 
 import gc
-import importlib.util
 import json
 import shutil
 import sys
@@ -30,8 +33,9 @@ import numpy as np
 from bench import check as C
 from bench import trace as TR
 from bench.client import Clients, percentile
-from bench.model import (init_weights, load_config, make_engine,
-                         program_config)
+from bench.model import (init_weights, load_config, load_family,
+                         load_module, make_engine, program_config,
+                         tp_context)
 from bench.peaks import peaks_for
 from bench.workload import ChatTraffic, load_mix
 
@@ -94,6 +98,7 @@ class WorkLog:
 class Run:
     """What a per-layer metric reader may read."""
     raw: Dict
+    family: object                # bench/families/<model_type>.py
     chips: int
     peaks: object
     window: tuple                 # host clock (start, end)
@@ -105,12 +110,7 @@ class Run:
 
 
 def _load_reader(root: Path, name: str) -> Callable:
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module(root / "bench" / "metrics" / f"{name}.py").read
 
 
 class GCPauses:
@@ -178,11 +178,10 @@ def prime_system_prompts(eng, traffic: ChatTraffic) -> None:
                 pending.discard(rid)
 
 
-def peak_bytes(devices) -> Optional[int]:
-    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
-             for d in devices]
-    peaks = [p for p in peaks if p is not None]
-    return max(peaks) if peaks else None
+def peak_bytes(devices) -> List[Optional[int]]:
+    """Each chip's peak bytes in use, where the backend reports it."""
+    return [(d.memory_stats() or {}).get("peak_bytes_in_use")
+            for d in devices]
 
 
 def window_metrics(clients: Clients, ws: float, we: float
@@ -227,9 +226,20 @@ def run_cell(root: Path, bench: Dict, workload: str, seed: int,
     cell = find(bench["workloads"], workload, "workload")
     cfg_entry = find(bench["configs"], cell["config"], "config")
     raw = load_config(root, cfg_entry)
-    mix = load_mix(root, cell["traffic"])
     chips = cell["chips"]
+    if chips != raw["serving"]["chips"]:
+        raise ValueError(
+            f"cell {workload!r} asks for {chips} chip(s), but its "
+            f"configuration {cfg_entry['file']} is deployed on "
+            f"{raw['serving']['chips']}: the two must agree")
+    family = load_family(root, raw)
+    cfg = program_config(raw, family)
+    mix = load_mix(root, cell["traffic"])
     devices = devices if devices is not None else jax.devices()[:chips]
+    if len(devices) != chips:
+        raise ValueError(f"cell {workload!r} needs {chips} devices, "
+                         f"given {len(devices)}")
+    tp = tp_context(cfg, devices)
     kind = devices[0].device_kind
     peaks = peaks if peaks is not None else peaks_for(kind)
     srv = raw["serving"]
@@ -239,7 +249,7 @@ def run_cell(root: Path, bench: Dict, workload: str, seed: int,
     def phase(name):
         phases[name] = time.perf_counter() - t_start
 
-    eng = make_engine(raw, seed, **(lower or {}))
+    eng = make_engine(raw, cfg, family, seed, tp, **(lower or {}))
     phase("engine")
     if engine_hook is not None:
         engine_hook(eng)
@@ -248,8 +258,7 @@ def run_cell(root: Path, bench: Dict, workload: str, seed: int,
         work.wrap(eng)
     warm_up(eng, srv["prefill_chunk"])
     phase("warm_up")
-    vocab = program_config(raw).vocab
-    traffic = ChatTraffic(mix, seed, vocab, srv["max_len"])
+    traffic = ChatTraffic(mix, seed, cfg.vocab, srv["max_len"])
     prime_system_prompts(eng, traffic)
     phase("prime")
     spans = jax.profiler.TraceAnnotation if trace else None
@@ -304,6 +313,7 @@ def run_cell(root: Path, bench: Dict, workload: str, seed: int,
     e2e = window_metrics(clients, ws, we)
     e2e["setup_s"] = setup_s
     mem = peak_bytes(devices)
+    known = [p for p in mem if p is not None]
     # served tokens to compare: requests the window finished, and those it
     # left running, with what they were served
     finished = [r for r in clients.finished if ws <= r.done < we]
@@ -312,12 +322,14 @@ def run_cell(root: Path, bench: Dict, workload: str, seed: int,
     gc.collect()
 
     # the comparison with the reference, once the program's state is freed;
-    # the weights are made again from the seed, as the program got them
+    # the weights are made again from the seed, as the program got them and
+    # in its placement
     t_ref = time.perf_counter()
-    weights = init_weights(raw, seed)
+    weights = init_weights(cfg, family, seed, tp)
     sample = C.sample(finished, running, seed, srv["check_min_tokens"],
                       srv["check_max_requests"])
-    ref = C.Reference(raw, srv["max_len"], mix["answer_tokens"]["max"])
+    ref = C.Reference(root, raw, srv["max_len"],
+                      mix["answer_tokens"]["max"])
     read = C.readings(ref, weights, sample)
     verdict = C.judge(read, raw["correct"])
     phases["reference_s"] = time.perf_counter() - t_ref
@@ -327,10 +339,11 @@ def run_cell(root: Path, bench: Dict, workload: str, seed: int,
     result = {"correct": verdict["correct"] and failed == 0,
               "attempted": attempted, "failed": failed}
     device = {"platform": devices[0].platform, "kind": kind,
-              "count": len(devices), "memory_peak_bytes": mem}
+              "count": len(devices),
+              "memory_peak_bytes": max(known) if known else None}
     if trace:
-        run = _traced(root, bench, workload, raw, chips, peaks, trace_dir,
-                      (ws, we), clients, stats0, stats1, work)
+        run = _traced(root, bench, workload, raw, family, chips, peaks,
+                      trace_dir, (ws, we), clients, stats0, stats1, work)
         result["metrics"], device["busy_s"], device["window_s"] = run[:3]
         result["breakdown"] = run[3]
     else:
@@ -347,6 +360,7 @@ def run_cell(root: Path, bench: Dict, workload: str, seed: int,
             "compared_tokens": read["tokens"],
             "compared_requests": len(sample),
             "compiles_in_window": window_compiles,
+            "memory_peak_bytes_per_chip": mem,
             "compile_s_total": compiles.seconds,
             "preemptions": stats1["n_preemptions"] - stats0["n_preemptions"],
             # pages of the pool in use and the contexts in flight (least,
@@ -367,8 +381,8 @@ def run_cell(root: Path, bench: Dict, workload: str, seed: int,
     return result
 
 
-def _traced(root, bench, workload, raw, chips, peaks, trace_dir, window,
-            clients, stats0, stats1, work):
+def _traced(root, bench, workload, raw, family, chips, peaks, trace_dir,
+            window, clients, stats0, stats1, work):
     files = sorted(trace_dir.glob("**/*.xplane.pb"))
     if not files:
         raise RuntimeError(f"the profiler wrote no trace under {trace_dir}")
@@ -380,7 +394,7 @@ def _traced(root, bench, workload, raw, chips, peaks, trace_dir, window,
     t0, t1 = win[0].start_ns, win[0].end_ns
     red = TR.reduce(events, t0, t1)
     ws, we = window
-    run = Run(raw, chips, peaks, window, clients, stats0, stats1,
+    run = Run(raw, family, chips, peaks, window, clients, stats0, stats1,
               work.between(ws, we), red)
     metrics = {}
     for m in cell_metrics(bench, "per_layer", workload):

@@ -18,5 +18,6 @@ def read(run):
     least = sum(F.least_seconds(w["attn_flops"], w["attn_bytes"],
                                 run.chips * p.bf16_flops,
                                 run.chips * p.hbm_bytes_per_s)
-                for w in (F.call_work(run.raw, pos) for _, _, pos in run.work))
+                for w in (F.call_work(run.raw, pos, family=run.family)
+                          for _, _, pos in run.work))
     return 100.0 * least / spent

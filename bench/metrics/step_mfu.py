@@ -11,7 +11,7 @@ def read(run):
         return None
     ops = 0.0
     for _, _, pos in run.work:
-        w = F.call_work(run.raw, pos)
+        w = F.call_work(run.raw, pos, family=run.family)
         ops += w["gemm_flops"] + w["attn_flops"]
     ws, we = run.window
     return 100.0 * ops / ((we - ws) * run.chips * run.peaks.bf16_flops)

@@ -8,6 +8,8 @@ import shutil
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[2]
+# the seed of the smoke runs, and of the weights checksum in test_chips.py
+SEED = 2**40 + 5
 
 SMOKE_CONFIG = {
     "name": "smoke-qwen2", "model_type": "qwen2", "hidden_act": "silu",
@@ -34,8 +36,9 @@ SMOKE_MIX = {
 
 
 def make_root(tmp: Path, config=SMOKE_CONFIG, mix=SMOKE_MIX,
-              name="smoke.chat") -> dict:
-    """Copy the benchmark into ``tmp`` and add the smoke cell; returns the
+              name="smoke.chat", chips=None) -> dict:
+    """Copy the benchmark into ``tmp`` and add the smoke cell, on the chips
+    its configuration names unless ``chips`` says otherwise; returns the
     new ``BENCHMARK.json``."""
     shutil.copytree(REPO / "bench", tmp / "bench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
@@ -49,14 +52,20 @@ def make_root(tmp: Path, config=SMOKE_CONFIG, mix=SMOKE_MIX,
         "why": "CPU rehearsal"})
     bench["workloads"].append({
         "name": name, "config": config["name"], "traffic": "smoke",
-        "chips": 1, "why": "CPU rehearsal"})
+        "chips": config["serving"]["chips"] if chips is None else chips,
+        "why": "CPU rehearsal"})
     (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
     return bench
 
 # wide enough that int8 weights, activations and KV pages read apart from
-# bf16 (at SMOKE_CONFIG's width they do not); limits set between the two
-# readings of seeds 1-3: the program's widest gap 0.013-0.029 and mean
-# gap 0.0004-0.0013, the int8 control's 0.088-0.187 and 0.0041-0.018
+# bf16 (at SMOKE_CONFIG's width they do not). The check draws 300-450
+# served tokens here (11-16 requests of a 6 s window on the CPU); seeds
+# 1-6 read, program then int8 control: max_gap 0.0642, 0.0337, 0.0600,
+# 0.0333, 0.0376, 0.0626 (highest 0.0642) and 0.2164, 0.1948, 0.1833,
+# 0.2372, 0.3387, 0.3074 (lowest 0.1833); mean_gap 0.00084, 0.00075,
+# 0.00113, 0.00053, 0.00069, 0.00067 (highest 0.00113) and 0.0098,
+# 0.0107, 0.0094, 0.0108, 0.0127, 0.0123 (lowest 0.0094). Each limit lies
+# between, nearer the geometric mean than either end
 CONTROL_CONFIG = dict(
     SMOKE_CONFIG, name="mid-qwen2", hidden_size=512, intermediate_size=1536,
     num_hidden_layers=4, num_attention_heads=8, num_key_value_heads=2,
@@ -64,7 +73,7 @@ CONTROL_CONFIG = dict(
     serving=dict(SMOKE_CONFIG["serving"], max_len=512, cache_pages=128,
                  prefill_chunk=64, check_min_tokens=400,
                  check_max_requests=16),
-    correct={"max_gap": 0.05, "mean_gap": 0.0025})
+    correct={"max_gap": 0.11, "mean_gap": 0.0035})
 CONTROL_MIX = dict(
     SMOKE_MIX, system_prompt_tokens=128,
     user_tokens={"median": 32, "sigma": 0.8, "min": 8, "max": 96},

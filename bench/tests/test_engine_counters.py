@@ -20,9 +20,9 @@ STATS1 = {"tick": 14, "engine_s": 6.0, "engine_wait_s": 5.3,
 
 
 def hand_run(stats0, stats1):
-    return Run(raw={}, chips=1, peaks=None, window=(0.0, 1.0),
-               clients=None, stats0=stats0, stats1=stats1, work=[],
-               reduction=None)
+    return Run(raw={}, family=None, chips=1, peaks=None,
+               window=(0.0, 1.0), clients=None, stats0=stats0,
+               stats1=stats1, work=[], reduction=None)
 
 
 def reader(name):

@@ -1,6 +1,7 @@
 """Operation and byte counts against hand counts at published widths."""
 import json
 from pathlib import Path
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -64,3 +65,17 @@ def test_call_work_counts_real_tokens_only():
 def test_least_seconds_takes_the_binding_bound():
     assert F.least_seconds(197e12, 0, 197e12, 819e9) == 1.0
     assert F.least_seconds(0, 819e9, 197e12, 819e9) == 1.0
+
+
+def test_a_family_count_takes_the_place_of_the_dense_one():
+    fam = SimpleNamespace(layer_matmuls=lambda r: [(10, 20), (20, 10)],
+                          kv_bytes_per_token=lambda r, itemsize: 7)
+    r = raw("qwen2-1.5b")
+    assert F.matmul_params(r, fam) == 28 * 400
+    assert F.kv_bytes_per_token(r, family=fam) == 7
+    pos = np.full((2, 4), -1)
+    pos[0, :2] = [5, 6]
+    w = F.call_work(r, pos, family=fam)
+    assert w["gemm_flops"] == 2.0 * (28 * 400 * 2 + 1536 * 151936 * 1)
+    # K/V read over the row's context of 7 plus the 2 written
+    assert w["attn_bytes"] == 7 * (7 + 2) + 2 * 2 * 28 * 12 * 128 * 2
